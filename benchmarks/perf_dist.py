@@ -1,8 +1,7 @@
 """Sharded packed-engine benchmark — BENCH_dist.json.
 
-Runs a 2-shard host-platform rung (XLA_FLAGS device-count override in a
-subprocess so the parent's jax stays single-device) against the single-host
-packed engine on the same world:
+Runs a 2-shard rung against the single-host packed engine on the same
+world, in this process, over the first two devices JAX sees:
 
   * ``shard2_speedup`` — warm W-window query, sharded / single-host. On one
     physical CPU two host "devices" time-slice the same cores, so this
@@ -14,54 +13,63 @@ packed engine on the same world:
     DESIGN.md §3, measured (≈0.5 + padding slack at 2 shards; the CI gate
     fails above 0.65).
 
-Both modes run: static RFS and streaming DRFS (quantized), warm.
+Both modes run: static RFS and streaming DRFS (quantized), warm. Run as a
+script on a CPU host, it asks XLA for two host devices before JAX starts
+(``--xla_force_host_platform_device_count=2``); any other caller must
+already have two devices.
 """
 import json
-import os
-import subprocess
 import sys
-import textwrap
+import time
 
-_WORKER = textwrap.dedent(
-    """
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-    import sys, json, time
-    sys.path.insert(0, "src")
-    import numpy as np
-    from repro.core import TNKDE
+sys.path.insert(0, "src")
+sys.path.insert(0, ".")
+if __name__ == "__main__":
+    from benchmarks.host_devices import request_host_devices
+
+    request_host_devices(2)
+import benchmarks.common  # noqa: F401,E402 (persistent compile cache)
+
+
+def _timed(m, ts) -> float:
+    m.query(ts)  # warm: compile + populate the plan/table caches
+    best = float("inf")
+    for _ in range(3):
+        t = time.perf_counter()
+        m.query(ts)
+        best = min(best, time.perf_counter() - t)
+    return best
+
+
+def run_dist_bench(scale: float = 0.04, n_windows: int = 5,
+                   out_json: str = "BENCH_dist.json") -> dict:
+    import jax
+
     from repro.compat import host_mesh
+    from repro.core import TNKDE
     from repro.data.spatial import make_dataset
 
-    scale = float(sys.argv[1])
-    n_windows = int(sys.argv[2])
-    net, ev, meta = make_dataset("berkeley", scale=scale, seed=0)
+    if len(jax.devices()) < 2:
+        raise RuntimeError(
+            f"the 2-shard rung needs two devices, JAX sees {len(jax.devices())}"
+        )
+    net, ev, _ = make_dataset("berkeley", scale=scale, seed=0)
     span = float(ev.time.max() - ev.time.min())
     t0 = float(ev.time.min())
     ts = [t0 + (i + 1) * span / (n_windows + 1) for i in range(n_windows)]
     b_t = span / 4
     mesh = host_mesh(2)
-    out = {"scale": scale, "W": n_windows, "N": int(ev.n), "rungs": []}
-
-    def timed(m):
-        m.query(ts)  # warm: compile + populate the plan/table caches
-        best = float("inf")
-        for _ in range(3):
-            t = time.perf_counter()
-            m.query(ts)
-            best = min(best, time.perf_counter() - t)
-        return best
-
+    rec = {"scale": scale, "W": n_windows, "N": int(ev.n), "rungs": []}
     for mode, kw in (
         ("rfs", dict(solution="rfs")),
         ("drfs_quantized", dict(solution="drfs", drfs_depth=6)),
     ):
         base = dict(g=50.0, b_s=400.0, b_t=b_t, **kw)
         single = TNKDE(net, ev, engine="jax", **base)
-        t_single = timed(single)
+        t_single = _timed(single, ts)
         sharded = TNKDE(net, ev, mesh=mesh, **base)
-        t_shard = timed(sharded)
-        out["rungs"].append(dict(
+        t_shard = _timed(sharded, ts)
+        rec["rungs"].append(dict(
             mode=mode,
             engine=sharded.engine_desc,
             t_single=round(t_single, 4),
@@ -73,27 +81,6 @@ _WORKER = textwrap.dedent(
                 sharded.stats.bytes_per_shard / max(single._fe.bytes_per_shard, 1), 3
             ),
         ))
-    print(json.dumps(out))
-    """
-)
-
-
-def run_dist_bench(scale: float = 0.04, n_windows: int = 5,
-                   out_json: str = "BENCH_dist.json") -> dict:
-    worker = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_dist_worker.py")
-    with open(worker, "w") as f:
-        f.write(_WORKER)
-    try:
-        res = subprocess.run(
-            [sys.executable, worker, str(scale), str(n_windows)],
-            capture_output=True, text=True, timeout=1800,
-            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        )
-        if res.returncode != 0:
-            raise RuntimeError(f"dist bench worker failed:\n{res.stderr[-3000:]}")
-        rec = json.loads(res.stdout.strip().splitlines()[-1])
-    finally:
-        os.unlink(worker)
     for r in rec["rungs"]:
         print(
             f"dist/{r['mode']},0.0,engine={r['engine']};"

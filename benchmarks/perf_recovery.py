@@ -37,8 +37,10 @@ import tempfile
 import time
 
 sys.path.insert(0, "src")
+sys.path.insert(0, ".")
 import numpy as np
 
+import benchmarks.common  # noqa: F401,E402 (persistent compile cache)
 from repro.core import TNKDE, WriteAheadLog
 from repro.core.events import Events
 from repro.data.spatial import make_dataset

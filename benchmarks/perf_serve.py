@@ -38,8 +38,10 @@ import sys
 import time
 
 sys.path.insert(0, "src")
+sys.path.insert(0, ".")
 import numpy as np
 
+import benchmarks.common  # noqa: F401,E402 (persistent compile cache)
 from repro.core import TNKDE
 from repro.core.events import Events
 from repro.core.rfs import _size_class

@@ -15,7 +15,15 @@ from __future__ import annotations
 import argparse
 import glob
 import json
+import os
+import sys
 import time
+
+if __name__ == "__main__":
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from benchmarks.host_devices import request_host_devices
+
+    request_host_devices(2)  # the BENCH_dist rung shards over two devices
 
 
 def _emit_kde(scale: float) -> None:
@@ -37,8 +45,15 @@ def _emit_serve(scale: float) -> None:
 
 
 def _emit_dist(scale: float) -> None:
+    import jax
+
     from benchmarks.perf_dist import run_dist_bench
 
+    n_dev = len(jax.devices())
+    if n_dev < 2:
+        print(f"# BENCH_dist.json skipped: the 2-shard rung needs two devices, "
+              f"JAX sees {n_dev} {jax.default_backend()} device")
+        return
     run_dist_bench(scale=scale, out_json="BENCH_dist.json")
 
 

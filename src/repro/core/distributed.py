@@ -42,9 +42,16 @@ from typing import Sequence, Tuple
 
 import numpy as np
 
-from .aggregation import N_COMBOS, next_pow2
+from .aggregation import N_COMBOS
 from .query_plan import PlanCache, route_atoms_by_shard
-from .rfs import _DeviceEngine, _device_nbytes, _size_class
+from .rfs import (
+    _DeviceEngine,
+    _device_nbytes,
+    _size_class,
+    feature_major,
+    pending_by_position,
+    pending_capacity,
+)
 
 __all__ = [
     "assign_edges",
@@ -216,6 +223,14 @@ def build_sharded_packed(rf, n_shards: int) -> ShardedPackedForest:
     )
 
 
+def _slab_keys(t: np.ndarray) -> np.ndarray:
+    """[S, N] host times → [S, 2, N] time keys (``jax_engine.time_key``),
+    shard axis first so each slab carries its own key planes."""
+    from .jax_engine import time_key
+
+    return np.ascontiguousarray(np.moveaxis(time_key(t), 0, 1))
+
+
 # ------------------------------------------------------------- programs
 _PROGRAMS: dict = {}  # (mesh, axes) -> dict of jitted shard_map programs
 # Module-level cache: every engine instance on the same mesh reuses one
@@ -305,7 +320,7 @@ def _get_programs(mesh, axes: Tuple[str, ...]):
     # ---- DRFS: window tables + flush ---------------------------------------
     @functools.partial(
         jax.jit,
-        static_argnames=("n_levels", "hq", "search_steps", "steps_per_level", "exact"),
+        static_argnames=("n_levels", "hq", "steps_per_level", "exact"),
     )
     def dyn_tables(forest, wb, *, n_levels, hq, search_steps, steps_per_level, exact):
         def body(forest, wb):
@@ -324,21 +339,21 @@ def _get_programs(mesh, axes: Tuple[str, ...]):
 
     @functools.partial(
         jax.jit,
-        static_argnames=("n_levels", "hq", "scan_steps", "pend_steps", "exact"),
+        static_argnames=("n_levels", "hq", "exact"),
     )
-    def dyn_flush(forest, fa, wb, tables, heat, *, n_levels, hq, scan_steps,
-                  pend_steps, exact):
-        def body(forest, fa, wb, tables, heat):
+    def dyn_flush(forest, fa, wb, tables, leaves, heat, *, n_levels, hq,
+                  scan_steps, pend_steps, exact):
+        def body(forest, fa, wb, tables, leaves, heat):
             fa_l = _local(fa)
             vals = eval_atoms_dyn(
-                _local(forest), fa_l, wb, tuple(t[0] for t in tables),
+                _local(forest), fa_l, wb, tuple(t[0] for t in tables), leaves[0],
                 n_levels=n_levels, hq=hq, scan_steps=scan_steps,
                 pend_steps=pend_steps, exact=exact,
             )
             return _psum_delta(vals, fa_l, heat)
 
-        return _smap(body, (spec, spec, rep, spec, rep), rep)(
-            forest, fa, wb, tables, heat
+        return _smap(body, (spec, spec, rep, spec, spec, rep), rep)(
+            forest, fa, wb, tables, leaves, heat
         )
 
     progs = dict(
@@ -371,6 +386,12 @@ class _ShardedBase(_DeviceEngine):
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         self._slab_sharding = NamedSharding(mesh, P(self.axes))
+        self._replicated = NamedSharding(mesh, P())
+
+    def _upload(self, x):
+        """Replicated upload (window batches, heatmaps): every device of
+        the mesh holds its own copy from the start."""
+        return self._jax.device_put(x, self._replicated)
 
     def _shard_put(self, x):
         """Upload a stacked [S, ...] host array with its shard axis placed
@@ -386,7 +407,7 @@ class _ShardedBase(_DeviceEngine):
         """Host-routed [S, Mp] atom fields → a device FlatAtoms, sharded."""
         from .jax_engine import FlatAtoms
 
-        with self._jax.experimental.enable_x64():
+        with self._precision():
             return FlatAtoms(**{k: self._shard_put(v) for k, v in fields.items()})
 
     @property
@@ -422,13 +443,13 @@ class ShardedForestEngine(_ShardedBase):
         self.search_steps = self.sf.search_steps
         from .jax_engine import PackedForest
 
-        with self._jax.experimental.enable_x64():
+        with self._precision():
             self._nbl = self._shard_put(self.sf.node_base_lvl)
             self._pf = PackedForest(
                 pm_pos=self._shard_put(self.sf.pm_pos),
                 pos_base=self._shard_put(self.sf.pos_base),
-                pm_time=self._shard_put(self.sf.pm_time),
-                pm_cum=self._shard_put(self.sf.pm_cum),
+                pm_time=self._shard_put(_slab_keys(self.sf.pm_time)),
+                pm_cum=self._shard_put(feature_major(self.sf.pm_cum)),
                 edge_base=self._shard_put(self.sf.edge_base),
                 n_pad=self._shard_put(self.sf.n_pad),
                 n_lev=self._shard_put(self.sf.n_lev),
@@ -457,7 +478,7 @@ class ShardedForestEngine(_ShardedBase):
         )
 
     def window_tables(self, wb, ts_key):
-        """Sharded q_t-folded node values [S, R·2, W, 2k_s], LRU per ts.
+        """Sharded q_t-folded node values [S, W·2k_s, 2R], LRU per ts.
 
         Same hoist, same builder (`packed_node_tables`), run per shard over
         the slab's node runs — all time searches stay at node-count scale.
@@ -467,7 +488,7 @@ class ShardedForestEngine(_ShardedBase):
         if hit is not None:
             return hit
         W = len(ts_key)
-        with self._jax.experimental.enable_x64():
+        with self._precision():
             tabs = self._progs["rfs_tables"](
                 self._pf, wb, self._node_starts,
                 steps_per_level=self.sf.steps_per_level,
@@ -491,7 +512,7 @@ class ShardedForestEngine(_ShardedBase):
                 atoms, self.sf.shard_of_edge, self.sf.edge_slot, self.n_shards
             )
             fa = self._upload_fa(fields)
-            with self._jax.experimental.enable_x64():
+            with self._precision():
                 r_lo, r_hi = self._progs["rfs_roots"](
                     self._pf, fa, search_steps=self.search_steps
                 )
@@ -505,7 +526,7 @@ class ShardedForestEngine(_ShardedBase):
             return heat
         tabs = self.window_tables(wb, ts_key)
         for entry in self._atom_packs(plan):
-            with self._jax.experimental.enable_x64():
+            with self._precision():
                 heat = self._progs["rfs_flush"](
                     tabs, self._nbl, entry["fa"], entry["r_lo"], entry["r_hi"],
                     heat, max_levels=self.max_levels,
@@ -527,7 +548,7 @@ class ShardedForestEngine(_ShardedBase):
         fields = route_atoms_by_shard(
             atoms, self.sf.shard_of_edge, self.sf.edge_slot, self.n_shards
         )
-        with jax.experimental.enable_x64():
+        with self._precision():
             fa = self._upload_fa(fields)
             tabs_s = jax.eval_shape(
                 ft.partial(
@@ -541,7 +562,10 @@ class ShardedForestEngine(_ShardedBase):
                 ft.partial(self._progs["rfs_roots"], search_steps=self.search_steps),
                 self._pf, fa,
             )
-            heat_s = jax.ShapeDtypeStruct((n_lixels, wb.t_lo.shape[0] // 2), jnp.float64)
+            heat_s = jax.ShapeDtypeStruct(
+                (n_lixels, wb.qt.shape[0] // 2),
+                jax.dtypes.canonicalize_dtype(jnp.float64),
+            )
             return self._progs["rfs_flush"].lower(
                 tabs_s, self._nbl, fa, r_s[0], r_s[1], heat_s,
                 max_levels=self.max_levels,
@@ -589,11 +613,6 @@ class ShardedDynamicEngine(_ShardedBase):
         ]
         for s, o in enumerate(self._owned):
             self._own_mask[s][o] = True
-        lens_local = np.ones((self.n_shards, self.El))
-        for s, o in enumerate(self._owned):
-            lens_local[s, : len(o)] = df.lens[o]
-        with self._jax.experimental.enable_x64():
-            self._lens_dev = self._shard_put(lens_local)
         self._sealed_packs: "OrderedDict" = OrderedDict()
         self._pend_packs: "OrderedDict" = OrderedDict()
         self._tab_cache: "OrderedDict" = OrderedDict()
@@ -607,7 +626,6 @@ class ShardedDynamicEngine(_ShardedBase):
     def device_bytes(self) -> int:
         return _device_nbytes(
             [
-                self._lens_dev,
                 list(self._sealed_packs.values()),
                 list(self._pend_packs.values()),
                 list(self._tab_cache.values()),
@@ -653,13 +671,12 @@ class ShardedDynamicEngine(_ShardedBase):
                 np.cumsum(cl.ravel(), out=node_ptr[s, off_d + 1 : off_d + El * (1 << d) + 1])
                 max_occ[d] = max(max_occ[d], int(cl.max(initial=0)))
         pack = _ShardedSealed()
-        with self._jax.experimental.enable_x64():
+        with self._precision():
             pack.tables = dict(
-                time_lvl=self._shard_put(time_lvl),
+                time_lvl=self._shard_put(_slab_keys(time_lvl)),
                 pos_lvl=self._shard_put(pos_lvl),
-                cum_lvl=self._shard_put(cum_lvl),
+                cum_lvl=self._shard_put(feature_major(cum_lvl)),
                 node_ptr=self._shard_put(node_ptr),
-                edge_len=self._lens_dev,
             )
         pack.n_levels = Lv
         pack.max_occ = max_occ
@@ -687,17 +704,18 @@ class ShardedDynamicEngine(_ShardedBase):
         csr = snap.pending_csr()
         pack = _ShardedPend()
         if csr is None:
+            Pp = pending_capacity(snap, 0)
             pptr = np.zeros((S, El + 1), np.int64)
-            pp = np.zeros((S, 1))
-            pt = np.full((S, 1), np.inf)
-            pf = np.zeros((S, 1, N_COMBOS, K))
+            pp = np.zeros((S, Pp))
+            pt = np.full((S, Pp), np.inf)
+            pf = np.zeros((S, Pp, N_COMBOS, K))
             pack.pend_steps = 0
         else:
-            gptr, gp, gt, gf = csr
+            gptr, gp, gt, gf = pending_by_position(csr)
             counts = np.diff(gptr)
             edge_of = np.repeat(np.arange(E, dtype=np.int64), counts)
             per_shard = np.bincount(self.shard_of[edge_of], minlength=S)
-            Pp = _size_class(max(int(per_shard.max(initial=1)), 1), floor=64)
+            Pp = pending_capacity(snap, int(per_shard.max(initial=1)))
             pptr = np.zeros((S, El + 1), np.int64)
             pp = np.zeros((S, Pp))
             pt = np.full((S, Pp), np.inf)
@@ -711,13 +729,13 @@ class ShardedDynamicEngine(_ShardedBase):
                 cl = np.zeros(El, np.int64)
                 cl[: len(o)] = counts[o]
                 np.cumsum(cl, out=pptr[s, 1:])
-            pack.pend_steps = next_pow2(int(counts.max(initial=1)))
-        with self._jax.experimental.enable_x64():
+            pack.pend_steps = int(counts.max(initial=1))
+        with self._precision():
             pack.tables = dict(
                 pend_ptr=self._shard_put(pptr),
                 pend_pos=self._shard_put(pp),
-                pend_time=self._shard_put(pt),
-                pend_phi=self._shard_put(pf),
+                pend_time=self._shard_put(_slab_keys(pt)),
+                pend_phi=self._shard_put(feature_major(pf)),
             )
         pack.nbytes = _device_nbytes(pack.tables)
         self._pend_packs[key] = pack
@@ -748,7 +766,7 @@ class ShardedDynamicEngine(_ShardedBase):
 
         W = len(ts_key)
         forest = self._forest(sealed, self._get_pending(snap))
-        with self._jax.experimental.enable_x64():
+        with self._precision():
             # only the active branch's trip counts enter the jit key — a
             # seal that moves an occupancy the other mode reads must not
             # recompile this one (mirrors the single-host engine, which
@@ -786,6 +804,23 @@ class ShardedDynamicEngine(_ShardedBase):
         self._pack_cache.put(key, packs)
         return packs
 
+    def _leaf_pack(self, entry, snap, hq: int):
+        """Host-resolved leaf bounds of one atom block at depth ``hq``
+        (``drfs.leaf_bounds``), routed like its atoms: [S, Mp, 4], cached
+        per (plan block, hq)."""
+        key = ("leaves", int(hq))
+        hit = entry.get(key)
+        if hit is None:
+            atoms = entry["atoms"]
+            lb = snap.leaf_bounds(atoms, hq).astype(np.int32)
+            routed = route_atoms_by_shard(
+                atoms, self.shard_of, self.edge_slot, self.n_shards,
+                pad_to=entry["fa"].valid.shape[1], extra={"leaves": lb},
+            )["leaves"]
+            with self._precision():
+                hit = entry[key] = self._shard_put(routed)
+        return hit
+
     def flush_plan(self, heat, plan, wb, ts_key, *, h0=None, exact_leaf=False,
                    snapshot=None, **_):
         """heat[L, W] += every atom block, snapshot-consistent, collective."""
@@ -810,9 +845,10 @@ class ShardedDynamicEngine(_ShardedBase):
             self.counters["moment_gathers"] += (
                 2 * (hq + 1) * entry["m"] if exact_leaf else 2 * entry["m"]
             )
-            with self._jax.experimental.enable_x64():
+            leaves = self._leaf_pack(entry, snap, hq)
+            with self._precision():
                 heat = self._progs["dyn_flush"](
-                    forest, entry["fa"], wb, tables, heat,
+                    forest, entry["fa"], wb, tables, leaves, heat,
                     n_levels=sealed.n_levels, hq=int(hq),
                     scan_steps=int(scan_steps), pend_steps=int(pend.pend_steps),
                     exact=bool(exact_leaf),

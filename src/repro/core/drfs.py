@@ -181,11 +181,9 @@ class _DrfsQueryView:
         mom = pref(i_hi) - pref(i_lo)
         return np.einsum("mk,mk->m", q_full[idx], mom)
 
-    def partial_leaf_targets(self, atoms, leaf_lo, leaf_hi, hq):
-        """(idx, node) pairs of the <= 2 partially covered boundary leaves
-        each atom must scan in exact mode, deduplicated. Shared by the host
-        scan and the device engine's work accounting."""
-        M = atoms.m
+    def boundary_leaves(self, atoms, leaf_lo, leaf_hi, hq):
+        """(cl, cu) [M] int64: the <= 2 partially covered boundary leaves
+        each atom must scan in exact mode, deduplicated; -1 = none."""
         nleaf = 1 << hq
         lens = self.lens[atoms.edge]
         w_leaf = lens / nleaf
@@ -210,9 +208,27 @@ class _DrfsQueryView:
         ok_cl = (cl >= 0) & (cl < lo_c)
         # scan cu when it is not inside the fully-covered range; dedup vs cl
         ok_cu = (cu >= 0) & ((cu < lo_c) | (cu >= hi_c)) & ~(ok_cl & (cu == cl))
+        return np.where(ok_cl, cl, -1), np.where(ok_cu, cu, -1)
+
+    def leaf_bounds(self, atoms, hq) -> np.ndarray:
+        """[M, 4] int64 rows (leaf_lo, leaf_hi, cl, cu) at depth hq: the
+        fully covered leaf range and the exact-mode boundary leaves.
+
+        The device engines take these as per-atom inputs instead of
+        recomputing them: every entry is a floor/ceil of an f64 division,
+        and an f32 device would move atoms across leaf boundaries —
+        dropping or doubling whole leaves in quantized mode."""
+        leaf_lo, leaf_hi = self.leaf_range(atoms, hq)
+        cl, cu = self.boundary_leaves(atoms, leaf_lo, leaf_hi, hq)
+        return np.stack([leaf_lo, leaf_hi, cl, cu], axis=1)
+
+    def partial_leaf_targets(self, atoms, leaf_lo, leaf_hi, hq):
+        """(idx, node) pairs of the boundary leaves of :meth:`boundary_leaves`.
+        Shared by the host scan and the device engine's work accounting."""
+        nleaf = 1 << hq
         pairs = []
-        for leaf, ok in ((cl, ok_cl), (cu, ok_cu)):
-            idx = np.nonzero(ok)[0]
+        for leaf in self.boundary_leaves(atoms, leaf_lo, leaf_hi, hq):
+            idx = np.nonzero(leaf >= 0)[0]
             if len(idx):
                 pairs.append((idx, atoms.edge[idx] * nleaf + leaf[idx]))
         return pairs

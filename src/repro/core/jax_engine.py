@@ -49,6 +49,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = [
     "FlatForest",
@@ -67,6 +68,7 @@ __all__ = [
     "dyn_window_tables",
     "dyn_node_tables",
     "dyn_node_base",
+    "time_key",
 ]
 
 
@@ -78,7 +80,7 @@ class FlatForest(NamedTuple):
     edge_base: jnp.ndarray  # [E] flat offset of each edge's block
     n_pad: jnp.ndarray  # [E] padded event count (power of two; 0 = no events)
     n_lev: jnp.ndarray  # [E] level count (log2(n_pad) + 1; 0 = no events)
-    time_flat: jnp.ndarray  # [N] per-edge time-sorted event times
+    time_flat: jnp.ndarray  # [2, N] per-edge time-sorted event time keys
     time_ptr: jnp.ndarray  # [E+1] event offsets
     bridge: jnp.ndarray  # [T] i32 left-child counts (zeros if not built)
 
@@ -115,15 +117,14 @@ class FlatDynamicForest(NamedTuple):
     ``insert -> query`` never waits for a rebuild.
     """
 
-    time_lvl: jnp.ndarray  # [Lv*Np] per-node time-sorted event times (+inf pad)
+    time_lvl: jnp.ndarray  # [2, Lv*Np] per-node time-sorted time keys (+inf pad)
     pos_lvl: jnp.ndarray  # [Lv*Np] event positions, same order
-    cum_lvl: jnp.ndarray  # [Lv*Np, 4, K] per-node inclusive prefix moments
+    cum_lvl: jnp.ndarray  # [4K, Lv*Np] per-node inclusive prefix moments (feature-major)
     node_ptr: jnp.ndarray  # [sum_d E*2^d + Lv] concatenated per-level node CSRs
-    edge_len: jnp.ndarray  # [E]
-    pend_ptr: jnp.ndarray  # [E+1] pending CSR by edge
+    pend_ptr: jnp.ndarray  # [E+1] pending CSR by edge, position-sorted per edge
     pend_pos: jnp.ndarray  # [Pp]
-    pend_time: jnp.ndarray  # [Pp]
-    pend_phi: jnp.ndarray  # [Pp, 4, K]
+    pend_time: jnp.ndarray  # [2, Pp] time keys
+    pend_phi: jnp.ndarray  # [4K, Pp] (feature-major)
 
 
 class PackedForest(NamedTuple):
@@ -149,8 +150,8 @@ class PackedForest(NamedTuple):
 
     pm_pos: jnp.ndarray  # [P] per-edge position-sorted values (+inf pad)
     pos_base: jnp.ndarray  # [E] flat offset of each edge's pm_pos block
-    pm_time: jnp.ndarray  # [T] level-major bucket tables, time-sorted
-    pm_cum: jnp.ndarray  # [T, 4, K] inclusive prefix moments (bucket-local)
+    pm_time: jnp.ndarray  # [2, T] level-major bucket time keys, time-sorted
+    pm_cum: jnp.ndarray  # [4K, T] inclusive prefix moments (bucket-local, feature-major)
     edge_base: jnp.ndarray  # [E] flat offset of each edge's level block
     n_pad: jnp.ndarray  # [E] padded event count (power of two; 0 = empty)
     n_lev: jnp.ndarray  # [E] level count
@@ -160,8 +161,8 @@ class PackedForest(NamedTuple):
 class WindowBatch(NamedTuple):
     """Per-half-window query tables: Wh = 2 · n_window_centers entries."""
 
-    t_lo: jnp.ndarray  # [Wh] window-half lower time bound
-    t_hi: jnp.ndarray  # [Wh] upper bound (always inclusive)
+    t_lo: jnp.ndarray  # [2, Wh] window-half lower time bound (time_key)
+    t_hi: jnp.ndarray  # [2, Wh] upper bound (always inclusive)
     lo_right: jnp.ndarray  # [Wh] bool: lower bound exclusive? (right halves)
     half: jnp.ndarray  # [Wh] i32 temporal orientation (0 = left, 1 = right)
     qt: jnp.ndarray  # [Wh, k_t] temporal coefficient vector
@@ -177,6 +178,16 @@ _CODEC_PRESETS = {
     "f32": dict(fold="float32", moment="float32", rtol=1e-5, pack_index=True),
     "bf16": dict(fold="bfloat16", moment="float32", rtol=2e-2, pack_index=True),
 }
+
+
+def _itemsize(name) -> int:
+    """Bytes per stored value of a codec table dtype; ``None`` (identity)
+    is the device path's float, which ``compat.device_x64`` decides."""
+    if name is None:
+        from ..compat import device_x64
+
+        return 8 if device_x64() else 4
+    return jnp.dtype(name).itemsize
 
 
 class TableCodec:
@@ -202,8 +213,11 @@ class TableCodec:
         indices, so x64 mode doesn't silently double the metadata bytes.
 
     Exactness rule: the ``f64`` preset (the ``'auto'`` default) is the
-    identity — bit-identical tables to the uncompressed layout, so every
-    exact mode keeps its ≤1e-12 cross-engine guarantee. Compressed presets
+    identity — the tables keep the device path's own float dtype
+    (``compat.device_x64``): float64 on CPU, bit-identical to the
+    uncompressed layout, so every exact mode keeps its ≤1e-12 cross-engine
+    guarantee there; float32 on an accelerator, where it is the same layout
+    as the ``f32`` preset without its build-time check. Compressed presets
     are validated at build time (:meth:`validate`) against the f64 host
     tables; a table family whose round-trip error exceeds the preset's
     tolerance falls back to f64 for that engine (``fallback_reason`` says
@@ -235,12 +249,18 @@ class TableCodec:
         return self.fold_name is None and self.moment_name is None
 
     @property
+    def float_itemsize(self) -> int:
+        """Bytes per value of the uncompressed level tables (the device
+        path's float: 8 on CPU, 4 on an accelerator)."""
+        return _itemsize(None)
+
+    @property
     def fold_itemsize(self) -> int:
-        return 8 if self.fold_name is None else jnp.dtype(self.fold_name).itemsize
+        return _itemsize(self.fold_name)
 
     @property
     def moment_itemsize(self) -> int:
-        return 8 if self.moment_name is None else jnp.dtype(self.moment_name).itemsize
+        return _itemsize(self.moment_name)
 
     def pack_index(self, arr):
         """int32-pack node/bucket metadata (identity for the f64 preset)."""
@@ -265,8 +285,8 @@ class TableCodec:
         host = np.asarray(host_moments, dtype=np.float64)
         narrow = self.fold_name or self.moment_name
         # round-trip in NumPy (ml_dtypes supplies bfloat16) — going through
-        # jnp here would silently truncate the f64 baseline outside an
-        # enable_x64 block and vacuously pass the check
+        # jnp here would silently truncate the f64 baseline outside an x64
+        # scope and vacuously pass the check
         import ml_dtypes
 
         ndt = np.dtype(narrow) if narrow != "bfloat16" else ml_dtypes.bfloat16
@@ -291,15 +311,60 @@ class TableCodec:
         return f"TableCodec({self.name!r})"
 
 
+def time_key(t) -> np.ndarray:
+    """Order-preserving int32 pair encoding of float64 times: [2, ...].
+
+    The device compares event times with window bounds only by order, and
+    f32 cannot hold them (at 7.8e6 s it steps by 0.5 s; epoch seconds step
+    by 128 s), so times cross to the device as the f64 bit pattern mapped
+    to a signed 64-bit key with the same order, split into (high, biased
+    low) int32 words — two leading planes, so the time axis stays the
+    minor (lane) axis on a TPU. Comparing two keys lexicographically
+    (:func:`_key_lt`) is exactly the float64 comparison on every backend,
+    so no event ever changes sides of a window bound. ±inf pads map to the
+    extreme keys.
+    """
+    t = np.ascontiguousarray(np.asarray(t, dtype=np.float64) + 0.0)  # -0 -> +0
+    b = t.view(np.int64)
+    key = np.where(b < 0, b ^ np.int64(0x7FFFFFFFFFFFFFFF), b)
+    hi = (key >> 32).astype(np.int32)
+    lo = ((key & 0xFFFFFFFF) - (1 << 31)).astype(np.int32)
+    return np.stack([hi, lo])
+
+
+def _key_lt(a, b):
+    """a < b for :func:`time_key` pairs (leading axis 2)."""
+    return (a[0] < b[0]) | ((a[0] == b[0]) & (a[1] < b[1]))
+
+
+def _key_le(a, b):
+    """a <= b for :func:`time_key` pairs."""
+    return ~_key_lt(b, a)
+
+
+def _bcast_key(t_b, shape):
+    """[2, 3, W] boundary keys → ``(2,) + shape`` with a trailing node axis."""
+    return jnp.broadcast_to(t_b[..., None], (2,) + tuple(shape))
+
+
 def _seg_search(vals, seg_lo, seg_hi, q, right, steps: int):
     """Branch-free binary search of q within vals[seg_lo:seg_hi], batched
-    over arbitrary leading dims (all args broadcast to a common shape)."""
+    over arbitrary leading dims (all args broadcast to a common shape).
+
+    Float ``vals`` (positions) compare as numbers; int32 ``vals`` are
+    :func:`time_key` pairs [2, T] searched with a [2, ...] key ``q``."""
+    keyed = jnp.issubdtype(vals.dtype, jnp.integer)
 
     def body(_, lh):
         lo, hi = lh
         mid = (lo + hi) >> 1
-        v = vals[jnp.where(lo < hi, mid, 0)]
-        go = jnp.where(right, v <= q, v < q) & (lo < hi)
+        at = jnp.where(lo < hi, mid, 0)
+        v = vals[:, at] if keyed else vals[at]
+        if keyed:
+            go = jnp.where(right, _key_le(v, q), _key_lt(v, q))
+        else:
+            go = jnp.where(right, v <= q, v < q)
+        go = go & (lo < hi)
         return jnp.where(go, mid + 1, lo), jnp.where(go | (lo >= hi), hi, mid)
 
     lo, _ = jax.lax.fori_loop(0, steps, body, (seg_lo, seg_hi))
@@ -406,7 +471,7 @@ def _engine_cascade(forest, atoms, wb, ranks, *, max_levels, search_steps):
     itself when the path bottoms out on an odd rank). Shared path prefixes
     of adjacent boundaries cancel exactly in floating point.
     """
-    Wh = wb.t_lo.shape[0]
+    Wh = wb.qt.shape[0]
     W = Wh // 2
     M = atoms.edge.shape[0]
     E = forest.time_ptr.shape[0] - 1
@@ -494,15 +559,14 @@ def rank_boundaries(forest: FlatForest, wb: WindowBatch, *, search_steps: int):
     once per (snapshot, window batch) and every flush re-uses them (the
     hoist that makes per-flush time-search work zero in steady state).
     """
-    W = wb.t_lo.shape[0] // 2
+    W = wb.qt.shape[0] // 2
     E = forest.time_ptr.shape[0] - 1
     t_b, right_b = _dyn_boundaries(wb)
     s_lo = jnp.broadcast_to(forest.time_ptr[:-1][None, None, :], (3, W, E)).astype(jnp.int32)
     s_hi = jnp.broadcast_to(forest.time_ptr[1:][None, None, :], (3, W, E)).astype(jnp.int32)
     r_b = (
         _seg_search(
-            forest.time_flat, s_lo, s_hi,
-            jnp.broadcast_to(t_b[..., None], (3, W, E)),
+            forest.time_flat, s_lo, s_hi, _bcast_key(t_b, (3, W, E)),
             jnp.broadcast_to(right_b[..., None], (3, W, E)), search_steps,
         )
         - s_lo
@@ -538,43 +602,71 @@ def packed_root_ranks(pf: PackedForest, atoms: FlatAtoms, *, search_steps: int):
     return r_lo.astype(jnp.int32), r_hi.astype(jnp.int32)
 
 
+_FOLD_CHUNK = 32768  # nodes per step of a chunked table build
+
+
+def _chunked(fold, s_lo, s_hi, chunk: int, out_len=None):
+    """Run ``fold(lo, hi) -> [..., m]`` over chunks of ``chunk`` runs and
+    concatenate the chunks' last axes: [..., out_len] (default len(s_lo)).
+
+    A ``lax.map`` over fixed-size chunks (empty runs pad the tail): the TPU
+    compiler then sees one chunk-sized body instead of a node-sized
+    program, which cut a full-scale table build's compile from 27 s to 9 s
+    and its temporaries from 4.0 to 1.5 GB (v5e compile rehearsal)."""
+    n = s_lo.shape[0]
+    ch = max(min(n, chunk), 1)
+    nc = -(-n // ch)
+    pad = nc * ch - n
+    lo = jnp.pad(s_lo, (0, pad)).reshape(nc, ch)
+    hi = jnp.pad(s_hi, (0, pad)).reshape(nc, ch)
+    out = jax.lax.map(lambda lh: fold(*lh), (lo, hi))  # [nc, ..., m]
+    out = jnp.moveaxis(out, 0, -2)
+    out = out.reshape(out.shape[:-2] + (-1,))
+    return out[..., : (n if out_len is None else out_len)]
+
+
 def _fold_node_level(time_tab, cum_tab, s_lo, s_hi, t_b, right_b, qtl, qtr,
                      steps: int, k_t: int, out_dtype=None):
-    """One level's q_t-folded paired node values: [NL·2, W, 2k_s].
+    """One level's q_t-folded paired node values: [W·2k_s, 2, NL].
 
     The shared fold of :func:`packed_node_tables` and
     :func:`dyn_node_tables`: per (boundary, window, node) binary search in
     the node's time-sorted run [s_lo, s_hi), raw-Φ prefix difference
-    (node-local rounding), combo slice per side/half, q_t contraction, and
-    the paired [k_s left | k_s right] row packing with W inside the row —
-    exactly the layout :func:`packed_walk` consumes.
+    (node-local rounding), combo slice per side/half, q_t contraction.
+    Feature-major throughout (``cum_tab`` is [4K, T]): the node axis stays
+    the minor axis of every intermediate, which a TPU stores unpadded —
+    a trailing axis of 2k_s = 4 values would be padded to 128 lanes.
+    Row w·2k_s + j of the result holds window w's [k_s left | k_s right]
+    coefficient j; the column axes are (side, node).
     """
-    NL = s_lo.shape[0]
     W = qtl.shape[0]
-    K = cum_tab.shape[-1]
+    K = cum_tab.shape[0] // 4
     k_s = K // k_t
-    i_b = _seg_search(
-        time_tab,
-        jnp.broadcast_to(s_lo[None, None], (3, W, NL)),
-        jnp.broadcast_to(s_hi[None, None], (3, W, NL)),
-        jnp.broadcast_to(t_b[..., None], (3, W, NL)),
-        jnp.broadcast_to(right_b[..., None], (3, W, NL)),
-        steps,
-    )
 
-    def pref(i):
-        v = cum_tab[jnp.maximum(i - 1, 0)]
-        return jnp.where((i > s_lo[None, None])[..., None, None], v, 0.0)
+    def fold(lo, hi):
+        n = lo.shape[0]
+        i_b = _seg_search(
+            time_tab,
+            jnp.broadcast_to(lo[None, None], (3, W, n)),
+            jnp.broadcast_to(hi[None, None], (3, W, n)),
+            _bcast_key(t_b, (3, W, n)),
+            jnp.broadcast_to(right_b[..., None], (3, W, n)),
+            steps,
+        )
+        # prefix rows at the boundaries, node-local: [4K, 3, W, n]
+        p = jnp.where(
+            (i_b > lo[None, None])[None], cum_tab[:, jnp.maximum(i_b - 1, 0)], 0.0
+        ).reshape(4, k_s, k_t, 3, W, n)  # combo, s, t (Φ's s-major features)
+        left = p[0::2, :, :, 1] - p[0::2, :, :, 0]  # combos (ψ_c|ψ_d, left half)
+        right = p[1::2, :, :, 2] - p[1::2, :, :, 1]  # combos (ψ_c|ψ_d, right)
+        vl = jnp.einsum("csawn,wa->cswn", left, qtl)  # [2, k_s, W, n]
+        vr = jnp.einsum("csawn,wa->cswn", right, qtr)
+        vv = jnp.concatenate([vl, vr], axis=1)  # [2 side, 2k_s, W, n]
+        return jnp.transpose(vv, (2, 1, 0, 3)).reshape(W * 2 * k_s, 2, n)
 
-    p = pref(i_b)
-    left = (p[1] - p[0])[..., 0::2, :].reshape(W, NL, 2, k_s, k_t)
-    right = (p[2] - p[1])[..., 1::2, :].reshape(W, NL, 2, k_s, k_t)
-    vl = jnp.einsum("wncst,wt->wncs", left, qtl)
-    vr = jnp.einsum("wncst,wt->wncs", right, qtr)
-    vv = jnp.concatenate([vl, vr], axis=-1)  # [W, NL, 2, 2k_s]
-    out = jnp.transpose(vv, (1, 2, 0, 3)).reshape(NL * 2, W, 2 * k_s)
+    out = _chunked(fold, s_lo, s_hi, _FOLD_CHUNK)
     # codec fold cast: search + prefix diff + q_t contraction all ran in the
-    # host-table dtype (f64); only the finished values shrink
+    # host-table dtype; only the finished values shrink
     return out if out_dtype is None else out.astype(out_dtype)
 
 
@@ -587,16 +679,17 @@ def packed_node_tables(
     k_t: int,
     out_dtype=None,
 ):
-    """q_t-folded paired window values of EVERY position-rank node: [R·2, W, C].
+    """q_t-folded paired window values of EVERY position-rank node: [W·C, 2R].
 
     ``node_starts`` is a tuple of per-level i32 arrays: the flat pm_time
     offsets of every level-ℓ node's time-sorted run (length 2^ℓ). Per node
     the three window boundaries are binary-searched in the run — O(nodes)
     total, NOT O(atoms) — the raw-Φ prefix rows are differenced node-locally
     and contracted with the temporal query vectors immediately, so the walk
-    gathers finished values. Row (node, side) = [k_s left-half | k_s right],
-    with the W axis inside the row: one walk gather moves every window's
-    value for a node at once. Node ids follow ``pf.node_base`` level-major.
+    gathers finished values. Column side·R + node holds, for every window
+    w, the C = 2k_s values [k_s left-half | k_s right] at rows w·C + j: one
+    walk gather moves every window's value for a node at once. Node ids
+    follow ``pf.node_base`` level-major.
     """
     t_b, right_b = _dyn_boundaries(wb)
     qtl, qtr = wb.qt[0::2], wb.qt[1::2]
@@ -609,22 +702,23 @@ def packed_node_tables(
                 qtl, qtr, int(steps_per_level[lev]), k_t, out_dtype,
             )
         )
-    return jnp.concatenate(parts, axis=0)
+    out = jnp.concatenate(parts, axis=2)
+    return out.reshape(out.shape[0], -1)
 
 
 def packed_walk(nodeval, node_base_lvl, eid, side, r_lo, r_hi, *, max_levels: int):
-    """Canonical ≤2-nodes-per-level walk over finished node values: [M, W, C].
+    """Canonical ≤2-nodes-per-level walk over finished node values: [W·C, M].
 
     The shared executor core for the static packed forest AND the DRFS
     exact-mode node tables (``node_base_lvl`` [Lmax, E] maps walk levels to
     flat node bases; DRFS supplies the complete-tree arithmetic bases).
-    State is [M] ints — no window axis — and each level pays exactly ONE
-    paired gather ([2, M] node rows, every window riding inside the row).
+    ``nodeval`` is [W·C, 2R] (column side·R + node). State is [M] ints — no
+    window axis — and each level pays exactly ONE paired gather ([W·C, 2, M]
+    node columns, every window riding inside the column).
     """
     M = eid.shape[0]
-    R2 = nodeval.shape[0]
-    W, C = nodeval.shape[1], nodeval.shape[2]
-    acc0 = jnp.zeros((M, W, C), nodeval.dtype)
+    R = nodeval.shape[1] // 2
+    acc0 = jnp.zeros((nodeval.shape[0], M), nodeval.dtype)
 
     def level_body(lev, state):
         l, r, acc = state
@@ -637,10 +731,10 @@ def packed_walk(nodeval, node_base_lvl, eid, side, r_lo, r_hi, *, max_levels: in
         b_r = r - 1
         r = jnp.where(emit_r, r - 1, r)
         on = jnp.stack([emit_l, emit_r])  # [2, M]
-        idx = (nb[None] + jnp.stack([b_l, b_r])) * 2 + side[None]
-        idx = jnp.clip(jnp.where(on, idx, 0), 0, R2 - 1)
-        rows = nodeval[idx]  # [2, M, W, C] — one paired gather per level
-        acc = acc + jnp.where(on[..., None, None], rows, 0.0).sum(0)
+        idx = side[None] * R + nb[None] + jnp.stack([b_l, b_r])
+        idx = jnp.clip(jnp.where(on, idx, 0), 0, 2 * R - 1)
+        cols = nodeval[:, idx]  # [W·C, 2, M] — one paired gather per level
+        acc = acc + jnp.where(on[None], cols, 0.0).sum(1)
         return l >> 1, r >> 1, acc
 
     _, _, acc = jax.lax.fori_loop(
@@ -648,6 +742,18 @@ def packed_walk(nodeval, node_base_lvl, eid, side, r_lo, r_hi, *, max_levels: in
         (r_lo.astype(jnp.int32), r_hi.astype(jnp.int32), acc0),
     )
     return acc
+
+
+def _walk_values(acc, qs):
+    """[W·2k_s, M] walk accumulators · q_s → per-half values ([W, M], [W, M]).
+
+    Elementwise multiply-reduce, NOT einsum: keeps duplicate window centers
+    bitwise identical on CPU XLA (the GEMM an einsum lowers to is not
+    row-deterministic across the window batch)."""
+    k_s = qs.shape[1]
+    a4 = acc.reshape(-1, 2, k_s, acc.shape[-1])
+    qsT = qs.T[None]  # [1, k_s, M]
+    return (a4[:, 0] * qsT).sum(1), (a4[:, 1] * qsT).sum(1)
 
 
 def eval_atoms_packed(
@@ -659,49 +765,17 @@ def eval_atoms_packed(
     callers fold halves and scatter onto lixels), but consuming the packed
     plan: precomputed root rank intervals + q_t-folded node value tables.
     """
-    k_s = atoms.qs.shape[1]
     acc = packed_walk(
         nodeval, node_base_lvl,
         atoms.edge.astype(jnp.int32), atoms.side_feat.astype(jnp.int32),
         r_lo, r_hi, max_levels=max_levels,
     )
-    # elementwise multiply-reduce, NOT einsum: keeps duplicate window centers
-    # bitwise identical on CPU XLA (see eval_atoms_dyn note)
-    val_l = (acc[..., :k_s] * atoms.qs[:, None, :]).sum(-1)  # [M, W]
-    val_r = (acc[..., k_s:] * atoms.qs[:, None, :]).sum(-1)
-    out = jnp.stack([val_l.T, val_r.T], axis=1).reshape(-1, atoms.edge.shape[0])
+    val_l, val_r = _walk_values(acc, atoms.qs)  # [W, M] each
+    out = jnp.stack([val_l, val_r], axis=1).reshape(-1, atoms.edge.shape[0])
     return jnp.where(atoms.valid[None, :], out, 0.0)
 
 
 # ===================================================================== DRFS
-def _dyn_leaf_range(forest, atoms, hq: int):
-    """Fully-covered leaf range [leaf_lo, leaf_hi) at depth hq: [M] i32 each.
-
-    Mirrors drfs.DynamicRangeForest.leaf_range, with min/max/clip done in the
-    float domain *before* the int cast so the ±inf pads of invalid atoms
-    collapse to empty ranges instead of tripping undefined float->int casts.
-    """
-    lens = forest.edge_len[atoms.edge]
-    nleaf = 1 << hq
-    w_leaf = lens / nleaf
-    hi_ok = jnp.minimum(jnp.floor(atoms.pos_hi / w_leaf), nleaf)
-    hi_ok = jnp.where(atoms.pos_hi >= lens, float(nleaf), jnp.maximum(hi_ok, 0.0))
-    lo1, lo2 = atoms.pos_lo1, atoms.pos_lo2
-    lo1_leaf = jnp.where(
-        jnp.isfinite(lo1),
-        jnp.where(
-            atoms.lo1_right,
-            jnp.floor(lo1 / w_leaf) + 1.0,  # need leaf start strictly > lo1
-            jnp.ceil(lo1 / w_leaf),
-        ),
-        0.0,
-    )
-    lo2_leaf = jnp.where(jnp.isfinite(lo2), jnp.ceil(lo2 / w_leaf), 0.0)
-    leaf_lo = jnp.clip(jnp.maximum(lo1_leaf, lo2_leaf), 0.0, float(nleaf))
-    leaf_hi = jnp.clip(hi_ok, 0.0, float(nleaf))
-    return leaf_lo.astype(jnp.int32), leaf_hi.astype(jnp.int32)
-
-
 def _dyn_pos_mask(atoms, p):
     """Event-position acceptance against the atom's three bounds: [M] bool."""
     lo1_ok = jnp.where(atoms.lo1_right, p > atoms.pos_lo1, p >= atoms.pos_lo1)
@@ -709,11 +783,12 @@ def _dyn_pos_mask(atoms, p):
 
 
 def _dyn_boundaries(wb: WindowBatch):
-    """(t_b [3, W], right_b [3, W]): the (lo, mid, hi) time boundaries per
-    window center — mid is shared by both halves, so W centers carry 3 rank
-    boundaries instead of 4 (the paired ``make_window_batch`` layout)."""
-    W = wb.t_lo.shape[0] // 2
-    t_b = jnp.stack([wb.t_lo[0::2], wb.t_hi[0::2], wb.t_hi[1::2]])
+    """(t_b [2, 3, W] time keys, right_b [3, W]): the (lo, mid, hi) time
+    boundaries per window center — mid is shared by both halves, so W
+    centers carry 3 rank boundaries instead of 4 (the paired
+    ``make_window_batch`` layout)."""
+    W = wb.qt.shape[0] // 2
+    t_b = jnp.stack([wb.t_lo[:, 0::2], wb.t_hi[:, 0::2], wb.t_hi[:, 1::2]], axis=1)
     right_b = jnp.stack(
         [jnp.zeros((W,), bool), jnp.ones((W,), bool), jnp.ones((W,), bool)]
     )
@@ -742,19 +817,20 @@ def dyn_window_tables(
     per-node time searches: all O(log)-factor work scales with the *node
     count* E·2^hq, not with atoms × windows.
 
-    Returns lcum [E·(nleaf+1)·2, W, 2K]: per (leaf-prefix, side) row the raw
-    paired moment vector [K left-half | K right-half] for every window (the
-    W axis rides INSIDE the row, so an atom's two prefix lookups are one
-    stacked gather serving all windows at once). Staying in raw Φ space
+    Returns lcum [W·2K, 2·E·(nleaf+1)], feature-major: column
+    side·E·(nleaf+1) + e·(nleaf+1) + leaf holds, for every window w, the
+    raw paired moment vector [K left-half | K right-half] at rows w·2K + j
+    (the W axis rides INSIDE the column, so an atom's two prefix lookups
+    are one stacked gather serving all windows at once). Staying in raw Φ space
     (q_t applied only after the caller differences two prefixes) keeps the
     prefix magnitudes at the event scale — the same association the NumPy
     path's per-node prefix scheme uses — so the leaf-prefix shortcut costs
     no precision even for kernels with large alternating q_t entries.
     """
-    Wh = wb.t_lo.shape[0]
+    Wh = wb.qt.shape[0]
     W = Wh // 2
-    K = forest.cum_lvl.shape[-1]
-    Np = forest.time_lvl.shape[0] // n_levels
+    K = forest.cum_lvl.shape[0] // 4
+    Np = forest.time_lvl.shape[1] // n_levels
     E = forest.pend_ptr.shape[0] - 1
     nleaf = 1 << hq
     NL = E * nleaf
@@ -762,39 +838,45 @@ def dyn_window_tables(
     s_lo = (hq * Np + forest.node_ptr[pb : pb + NL]).astype(jnp.int32)
     s_hi = (hq * Np + forest.node_ptr[pb + 1 : pb + NL + 1]).astype(jnp.int32)
     t_b, right_b = _dyn_boundaries(wb)
-    i_b = _seg_search(
-        forest.time_lvl,
-        jnp.broadcast_to(s_lo[None, None], (3, W, NL)),
-        jnp.broadcast_to(s_hi[None, None], (3, W, NL)),
-        jnp.broadcast_to(t_b[..., None], (3, W, NL)),
-        jnp.broadcast_to(right_b[..., None], (3, W, NL)),
-        search_steps,
-    )  # [3, W, NL]
 
-    def pref(i):
-        v = forest.cum_lvl[jnp.maximum(i - 1, 0)]  # [3, W, NL, 4, K]
-        return jnp.where((i > s_lo[None, None])[..., None, None], v, 0.0)
+    def fold(lo, hi):  # whole edges: [W, 2K, 2, n // nleaf, nleaf + 1]
+        n = lo.shape[0]
+        i_b = _seg_search(
+            forest.time_lvl,
+            jnp.broadcast_to(lo[None, None], (3, W, n)),
+            jnp.broadcast_to(hi[None, None], (3, W, n)),
+            _bcast_key(t_b, (3, W, n)),
+            jnp.broadcast_to(right_b[..., None], (3, W, n)),
+            search_steps,
+        )
+        # prefix rows at the boundaries, feature-major: [4K, 3, W, n]
+        p = jnp.where(
+            (i_b > lo[None, None])[None], forest.cum_lvl[:, jnp.maximum(i_b - 1, 0)], 0.0
+        ).reshape(4, K, 3, W, n)
+        # per-leaf window moments, paired per side: [side, K left | K right]
+        left = p[0::2, :, 1] - p[0::2, :, 0]  # [2, K, W, n] combos (ψ·left)
+        right = p[1::2, :, 2] - p[1::2, :, 1]  # combos (ψ·right)
+        lv = jnp.concatenate([left, right], axis=1)  # [2, 2K, W, n]
+        # per-edge inclusive leaf prefix with a leading zero column; the leaf
+        # axis stays minor (unpadded on a TPU)
+        cum = jnp.transpose(lv, (2, 1, 0, 3)).reshape(W, 2 * K, 2, n // nleaf, nleaf)
+        if out_dtype is not None:
+            # delta-encoded compressed prefix: quantize the per-leaf DELTAS
+            # to the storage dtype first, then accumulate the prefix in the
+            # table dtype over the quantized deltas — a prefix difference
+            # recovers the quantized per-leaf value exactly (up to the final
+            # storage cast) instead of cancelling two large prefixes
+            cum = cum.astype(out_dtype).astype(cum.dtype)
+        cum = jnp.cumsum(cum, axis=-1)
+        return jnp.concatenate([jnp.zeros_like(cum[..., :1]), cum], axis=-1)
 
-    p = pref(i_b)
-    # per-leaf window moments, paired per side: [.., side] = [K left | K right]
-    left = (p[1] - p[0])[..., 0::2, :]  # [W, NL, 2, K] combos (ψ·left)
-    right = (p[2] - p[1])[..., 1::2, :]  # combos (ψ·right)
-    lv = jnp.concatenate([left, right], axis=-1)  # [W, NL, 2, 2K]
-    # per-edge inclusive leaf prefix with a leading zero row, laid out
-    # row-major [E*(nleaf+1)*2, W, 2K] for one-stacked-gather addressing
-    cum = lv.reshape(W, E, nleaf, 2, 2 * K)
-    if out_dtype is not None:
-        # delta-encoded compressed prefix: quantize the per-leaf DELTAS to
-        # the storage dtype first, then accumulate the prefix in f64 over
-        # the quantized deltas — a prefix difference recovers the quantized
-        # per-leaf value exactly (up to the final storage cast) instead of
-        # cancelling two large full-precision prefixes
-        cum = cum.astype(out_dtype).astype(cum.dtype)
-    cum = jnp.cumsum(cum, axis=2)
-    cum = jnp.concatenate([jnp.zeros_like(cum[:, :, :1]), cum], axis=2)
-    out = jnp.transpose(cum, (1, 2, 3, 0, 4)).reshape(
-        E * (nleaf + 1) * 2, W, 2 * K
-    )
+    # chunks of whole edges (the prefix runs along each edge's leaves)
+    ec = max(_FOLD_CHUNK // nleaf, 1)
+    out = _chunked(
+        lambda lo, hi: fold(lo, hi).reshape(W, 2 * K, 2, -1),
+        s_lo, s_hi, ec * nleaf, out_len=E * (nleaf + 1),
+    )  # [W, 2K, 2, E·(nleaf+1)] — each chunk's edges stay contiguous
+    out = out.reshape(W * 2 * K, 2 * E * (nleaf + 1))
     return out if out_dtype is None else out.astype(out_dtype)
 
 
@@ -819,12 +901,11 @@ def dyn_node_tables(
     kernels with large alternating q_t entries.
 
     Returns the packed node-value layout consumed by :func:`packed_walk`:
-    nodeval [TN·2, W, 2k_s] with TN = E·(2^{hq+1}−1); node (d, e, i) lives
-    at flat row (E·(2^d−1) + e·2^d + i)·2 + side, each row packing
-    [k_s left-half | k_s right-half] for every window — the same executor
-    layout the static packed forest uses.
+    nodeval [W·2k_s, 2·TN] with TN = E·(2^{hq+1}−1); node (d, e, i) is
+    node id E·(2^d−1) + e·2^d + i, at column side·TN + id — the same
+    executor layout the static packed forest uses.
     """
-    Np = forest.time_lvl.shape[0] // n_levels
+    Np = forest.time_lvl.shape[1] // n_levels
     E = forest.pend_ptr.shape[0] - 1
     k_t = wb.qt.shape[1]
     t_b, right_b = _dyn_boundaries(wb)
@@ -841,7 +922,8 @@ def dyn_node_tables(
                 qtl, qtr, int(steps_per_level[d]), k_t, out_dtype,
             )
         )
-    return jnp.concatenate(parts, axis=0)
+    out = jnp.concatenate(parts, axis=2)
+    return out.reshape(out.shape[0], -1)
 
 
 def dyn_node_base(E: int, hq: int) -> jnp.ndarray:
@@ -860,6 +942,7 @@ def eval_atoms_dyn(
     atoms: FlatAtoms,
     wb: WindowBatch,
     tables,
+    leaves,
     *,
     n_levels: int,
     hq: int,
@@ -875,7 +958,10 @@ def eval_atoms_dyn(
 
     Same contract as :func:`eval_atoms_flat` (callers fold the two halves of
     each window center and scatter onto lixels; requires the paired
-    ``make_window_batch`` row layout). Three phases, all window-batched:
+    ``make_window_batch`` row layout). ``leaves`` [M, 4] i32 carries each
+    atom's host-resolved (leaf_lo, leaf_hi, cl, cu) at depth ``hq``
+    (``drfs.DynamicRangeForest.leaf_bounds``; -1 = no boundary leaf).
+    Three phases, all window-batched:
 
       1. the fully-covered leaf range [leaf_lo, leaf_hi) at depth ``hq``.
          Quantized mode: two gathers into the per-edge leaf prefix tables
@@ -884,30 +970,37 @@ def eval_atoms_dyn(
          values of :func:`dyn_node_tables` (``tables`` = (vl, vr)) — same
          node set and rounding locality as the NumPy decomposition;
       2. ``exact`` mode: the <= 2 partially covered boundary leaves are
-         scanned with a fixed-trip masked loop (``scan_steps`` = max leaf
-         occupancy) — the beyond-paper exactness path;
-      3. pending (unsealed) events: a masked per-edge CSR scan
-         (``pend_steps`` = max per-edge pending count), so streaming inserts
-         are visible to queries without any rebuild.
+         scanned with a masked loop (``scan_steps`` = max leaf occupancy)
+         — the beyond-paper exactness path;
+      3. pending (unsealed) events: :func:`_pending_moments` (two
+         position searches per atom into its edge's pending run and one
+         gather from per-window segmented prefix tables; ``pend_steps`` =
+         max per-edge pending count), so streaming inserts are visible to
+         queries without any rebuild.
+
+    ``scan_steps`` and ``pend_steps`` may be traced: callers pass them as
+    jit arguments, so a stream whose occupancies grow never recompiles.
     """
-    Wh = wb.t_lo.shape[0]
+    Wh = wb.qt.shape[0]
     W = Wh // 2
     M = atoms.edge.shape[0]
-    K = forest.cum_lvl.shape[-1]
-    Np = forest.time_lvl.shape[0] // n_levels
+    K = forest.cum_lvl.shape[0] // 4
+    Np = forest.time_lvl.shape[1] // n_levels
     E = forest.pend_ptr.shape[0] - 1
     eid = atoms.edge.astype(jnp.int32)
     side = atoms.side_feat.astype(jnp.int32)
     nleaf = 1 << hq
     t_b, _ = _dyn_boundaries(wb)
-    cum2 = forest.cum_lvl.reshape(-1, 2, 2 * K)  # [i, side] = [K left | K right]
+    # [side, K left | K right, event]: combos (ψ_c·l, ψ_c·r, ψ_d·l, ψ_d·r)
+    cum3 = forest.cum_lvl.reshape(2, 2 * K, -1)
 
     # ---- phase 1: fully-covered leaf range [leaf_lo, leaf_hi) -------------
-    leaf_lo, leaf_hi = _dyn_leaf_range(forest, atoms, hq)
-    leaf_hi = jnp.maximum(leaf_hi, leaf_lo)
-    # scan phases accumulate raw Φ moments (q_t applied at the end)
-    mom_l = jnp.zeros((W, M, K), forest.cum_lvl.dtype)
-    mom_r = jnp.zeros((W, M, K), forest.cum_lvl.dtype)
+    leaf_lo = leaves[:, 0]
+    leaf_hi = jnp.maximum(leaves[:, 1], leaf_lo)
+    # scan phases accumulate raw Φ moments (q_t applied at the end),
+    # feature-major [K, W, M] so the atom axis stays minor
+    mom_l = jnp.zeros((K, W, M), forest.cum_lvl.dtype)
+    mom_r = jnp.zeros((K, W, M), forest.cum_lvl.dtype)
     k_s = atoms.qs.shape[1]
     acc = None
     if exact and tree:
@@ -915,103 +1008,152 @@ def eval_atoms_dyn(
         acc = packed_walk(
             nodeval, dyn_node_base(E, hq), eid, side, leaf_lo, leaf_hi,
             max_levels=hq + 1,
-        )  # [M, W, 2k_s]
+        )  # [W·2k_s, M]
     elif tree:
         (lcum,) = tables
-        base = eid * ((nleaf + 1) * 2) + side
-        idx = base[None] + jnp.stack([leaf_hi, leaf_lo]) * 2  # [2, M]
-        rows = lcum[idx]  # one stacked gather: [2, M, W, 2K]
-        tv = jnp.transpose(rows[0] - rows[1], (1, 0, 2))  # [W, M, 2K]
-        mom_l = mom_l + tv[..., :K]  # paired halves
-        mom_r = mom_r + tv[..., K:]
+        EL = E * (nleaf + 1)
+        base = side * EL + eid * (nleaf + 1)
+        idx = base[None] + jnp.stack([leaf_hi, leaf_lo])  # [2, M]
+        cols = lcum[:, idx]  # one stacked gather: [W·2K, 2, M]
+        tv = (cols[:, 0] - cols[:, 1]).reshape(W, 2, K, M)
+        mom_l = mom_l + jnp.transpose(tv[:, 0], (1, 0, 2))  # paired halves
+        mom_r = mom_r + jnp.transpose(tv[:, 1], (1, 0, 2))
 
-    def masked_event_scan(mom_l, mom_r, s_lo, s_hi, on, times, poss, steps, prefix):
-        """Fixed-trip scan of the per-atom runs [s_lo, s_hi), masked by on.
+    feat = jnp.arange(2 * K)[:, None, None]  # [2K, 1, 1]
 
-        ``prefix`` selects how Φ rows are recovered: True differenced from
-        the inclusive per-node prefix table (sealed levels), False gathered
-        raw (pending buffer)."""
-        table = cum2 if prefix else forest.pend_phi.reshape(-1, 2, 2 * K)
+    def masked_event_scan(mom_l, mom_r, s_lo, s_hi, on, steps):
+        """Masked scan of the per-atom sealed-event runs [s_lo, s_hi),
+        ``steps`` trips; Φ rows are differenced from the inclusive
+        per-node prefix table."""
 
         def body(j, ms):
             ml, mr = ms
             i = s_lo + j
             valid = on & (i < s_hi)
             idx = jnp.where(valid, i, 0)
-            te = times[idx]
-            p = poss[idx]
-            if prefix:
-                # per-event Φ from the inclusive prefix rows, both rows in
-                # ONE stacked gather
-                idx2 = jnp.stack([idx, jnp.maximum(idx - 1, 0)])
-                rows2 = table[idx2, side[None]]  # [2, M, 2K]
-                prev = jnp.where(j > 0, rows2[1], 0.0)
-                row = rows2[0] - prev
-            else:
-                row = table[idx, side]  # [M, 2K]
+            te = forest.time_lvl[:, idx]  # [2, M] time keys
+            p = forest.pos_lvl[idx]
+            # per-event Φ from the inclusive prefix rows, both rows in ONE
+            # stacked gather
+            idx2 = jnp.stack([idx, jnp.maximum(idx - 1, 0)])
+            rows2 = cum3[side[None, None], feat, idx2[None]]  # [2K, 2, M]
+            prev = jnp.where(j > 0, rows2[:, 1], 0.0)
+            row = rows2[:, 0] - prev
             keep = valid & _dyn_pos_mask(atoms, p)
-            m_l = (te[None] >= t_b[0][:, None]) & (te[None] <= t_b[1][:, None])
-            m_r = (te[None] > t_b[1][:, None]) & (te[None] <= t_b[2][:, None])
-            ml = ml + jnp.where((m_l & keep[None])[..., None], row[None, :, :K], 0.0)
-            mr = mr + jnp.where((m_r & keep[None])[..., None], row[None, :, K:], 0.0)
+            tw = t_b[:, :, :, None]  # [2, 3, W, 1] boundary keys
+            te = te[:, None, :]  # [2, 1, M]
+            m_l = _key_le(tw[:, 0], te) & _key_le(te, tw[:, 1])
+            m_r = _key_lt(tw[:, 1], te) & _key_le(te, tw[:, 2])
+            ml = ml + jnp.where((m_l & keep[None])[None], row[:K, None, :], 0.0)
+            mr = mr + jnp.where((m_r & keep[None])[None], row[K:, None, :], 0.0)
             return ml, mr
 
         return jax.lax.fori_loop(0, steps, body, (mom_l, mom_r))
 
     # ---- phase 2 (exact mode): partially covered boundary leaves ----------
-    if exact and scan_steps > 0:
-        lens = forest.edge_len[atoms.edge]
-        w_leaf = lens / nleaf
+    if exact:
         pb = E * (nleaf - 1) + hq
-        lo_eff = jnp.maximum(
-            jnp.where(jnp.isfinite(atoms.pos_lo1), atoms.pos_lo1, -jnp.inf),
-            jnp.where(jnp.isfinite(atoms.pos_lo2), atoms.pos_lo2, -jnp.inf),
-        )
-        cl = jnp.where(
-            jnp.isfinite(lo_eff),
-            jnp.clip(jnp.floor(lo_eff / w_leaf), 0.0, nleaf - 1.0),
-            -1.0,
-        ).astype(jnp.int32)
-        cu_f = jnp.clip(jnp.floor(jnp.maximum(atoms.pos_hi, 0.0) / w_leaf), -1.0, nleaf - 1.0)
-        cu = jnp.where(
-            (atoms.pos_hi >= lens) | (atoms.pos_hi < 0), -1.0, cu_f
-        ).astype(jnp.int32)
-        ok_cl = (cl >= 0) & (cl < leaf_lo)
-        ok_cu = (cu >= 0) & ((cu < leaf_lo) | (cu >= leaf_hi)) & ~(ok_cl & (cu == cl))
-        for leaf, ok in ((cl, ok_cl), (cu, ok_cu)):
+        for leaf in (leaves[:, 2], leaves[:, 3]):
             pidx = pb + eid * nleaf + jnp.clip(leaf, 0, nleaf - 1)
             s_lo = (hq * Np + forest.node_ptr[pidx]).astype(jnp.int32)
             s_hi = (hq * Np + forest.node_ptr[pidx + 1]).astype(jnp.int32)
             mom_l, mom_r = masked_event_scan(
-                mom_l, mom_r, s_lo, s_hi, ok,
-                forest.time_lvl, forest.pos_lvl, scan_steps, True,
+                mom_l, mom_r, s_lo, s_hi, leaf >= 0, scan_steps
             )
 
     # ---- phase 3: pending (unsealed) events -------------------------------
-    if pend_steps > 0:
-        p_lo = forest.pend_ptr[atoms.edge].astype(jnp.int32)
-        p_hi = forest.pend_ptr[atoms.edge + 1].astype(jnp.int32)
-        mom_l, mom_r = masked_event_scan(
-            mom_l, mom_r, p_lo, p_hi, jnp.ones((M,), bool),
-            forest.pend_time, forest.pend_pos, pend_steps, False,
-        )
+    pend_l, pend_r = jax.lax.cond(
+        jnp.asarray(pend_steps) > 0,
+        lambda: _pending_moments(forest, atoms, t_b, pend_steps),
+        lambda: (jnp.zeros_like(mom_l), jnp.zeros_like(mom_r)),
+    )
+    mom_l = mom_l + pend_l
+    mom_r = mom_r + pend_r
 
     # ---- contraction with the factored query ------------------------------
     k_t = wb.qt.shape[1]
     val_l = jnp.einsum(
-        "wmst,ms,wt->wm", mom_l.reshape(W, M, k_s, k_t), atoms.qs, wb.qt[0::2]
+        "stwm,ms,wt->wm", mom_l.reshape(k_s, k_t, W, M), atoms.qs, wb.qt[0::2]
     )
     val_r = jnp.einsum(
-        "wmst,ms,wt->wm", mom_r.reshape(W, M, k_s, k_t), atoms.qs, wb.qt[1::2]
+        "stwm,ms,wt->wm", mom_r.reshape(k_s, k_t, W, M), atoms.qs, wb.qt[1::2]
     )
     if acc is not None:
-        # elementwise multiply-reduce, NOT einsum: the GEMM einsum lowers to
-        # is not row-deterministic across the w batch on CPU XLA, which would
-        # make duplicate window centers differ by an ulp
-        val_l = val_l + (acc[..., :k_s] * atoms.qs[:, None, :]).sum(-1).T
-        val_r = val_r + (acc[..., k_s:] * atoms.qs[:, None, :]).sum(-1).T
+        acc_l, acc_r = _walk_values(acc, atoms.qs)
+        val_l = val_l + acc_l
+        val_r = val_r + acc_r
     out = jnp.stack([val_l, val_r], axis=1).reshape(Wh, M)
     return jnp.where(atoms.valid[None, :], out, 0.0)
+
+
+def _bit_length(n):
+    """Traced int32 ``n.bit_length()`` (0 for n <= 0)."""
+    n = jnp.maximum(jnp.asarray(n, jnp.int32), 0)
+    return 32 - jax.lax.clz(n)
+
+
+def _pending_moments(forest: FlatDynamicForest, atoms: FlatAtoms, t_b, pend_steps):
+    """Raw-Φ window moments of the pending events of every atom: ([K, W, M]
+    left halves, [K, W, M] right halves), feature-major.
+
+    The pending buffer is sorted by (edge, position), so the events an
+    atom's position bounds accept are one run [j_lo, j_hi) of its edge's
+    segment, found by binary searches (``pend_steps`` = max per-edge count
+    bounds their trips). The run is summed as power-of-two blocks, one per
+    set bit of its length, smallest first: level k of the loop holds every
+    window's time-masked Φ rows summed over 2^k consecutive rows (built by
+    doubling), and each atom gathers at most one block column per level.
+    Blocks lie inside the run, so nothing is differenced and the rounding
+    is that of summing the run's own events. The cost grows with log2 of
+    the per-edge pending count, not with the count itself.
+    """
+    W = t_b.shape[2]
+    K = forest.pend_phi.shape[0] // 4
+    Pp = forest.pend_pos.shape[0]
+    M = atoms.edge.shape[0]
+    eid = atoms.edge.astype(jnp.int32)
+    side = atoms.side_feat.astype(jnp.int32)
+    ptr = forest.pend_ptr.astype(jnp.int32)
+    nbits = _bit_length(pend_steps)
+
+    # ---- each atom's run [j_lo, j_hi) within its edge ----------------------
+    s_lo = ptr[eid]
+    s_hi = ptr[eid + 1]
+    steps = nbits + 1
+    pos = forest.pend_pos
+    j_lo = jnp.maximum(
+        _seg_search(pos, s_lo, s_hi, atoms.pos_lo1, atoms.lo1_right, steps),
+        _seg_search(pos, s_lo, s_hi, atoms.pos_lo2, jnp.zeros((M,), bool), steps),
+    )
+    j_hi = _seg_search(pos, s_lo, s_hi, atoms.pos_hi, jnp.ones((M,), bool), steps)
+    length = jnp.maximum(j_hi - j_lo, 0)
+
+    # ---- time-masked Φ rows per window half: [W·2·K, side, Pp] -------------
+    te = forest.pend_time[:, None, :]  # [2, 1, Pp]
+    tw = t_b[:, :, :, None]  # [2, 3, W, 1]
+    m_l = _key_le(tw[:, 0], te) & _key_le(te, tw[:, 1])  # [W, Pp]
+    m_r = _key_lt(tw[:, 1], te) & _key_le(te, tw[:, 2])
+    phi = forest.pend_phi.reshape(2, 2, K, Pp)  # [side, half, K, Pp]
+    mask = jnp.stack([m_l, m_r], axis=1)  # [W, half, Pp]
+    rows = jnp.where(
+        mask[:, :, None, None], jnp.transpose(phi, (1, 2, 0, 3))[None], 0.0
+    ).reshape(W * 2 * K, 2, Pp)
+
+    def level(k, carry):
+        blocks, acc, at = carry  # blocks[..., j] = Σ rows[..., j - 2^k + 1 : j + 1]
+        d = jnp.left_shift(jnp.int32(1), k)
+        take = (jnp.right_shift(length, k) & 1) == 1
+        col = side * Pp + jnp.clip(at + d - 1, 0, Pp - 1)
+        acc = acc + jnp.where(take[None], blocks.reshape(-1, 2 * Pp)[:, col], 0.0)
+        at = at + jnp.where(take, d, 0)
+        padded = jnp.concatenate([jnp.zeros_like(blocks), blocks], axis=-1)
+        prev = jax.lax.dynamic_slice_in_dim(padded, Pp - d, Pp, axis=-1)
+        return blocks + prev, acc, at
+
+    acc0 = jnp.zeros((W * 2 * K, M), rows.dtype)
+    _, acc, _ = jax.lax.fori_loop(0, nbits, level, (rows, acc0, j_lo))
+    run = acc.reshape(W, 2, K, M)
+    return jnp.transpose(run[:, 0], (1, 0, 2)), jnp.transpose(run[:, 1], (1, 0, 2))
 
 
 @functools.partial(jax.jit, static_argnames=("max_levels", "search_steps", "cascade"))
@@ -1040,7 +1182,7 @@ def eval_atoms_flat(
             max_levels=max_levels, search_steps=search_steps,
         )
     else:
-        Wh = wb.t_lo.shape[0]
+        Wh = wb.qt.shape[0]
         W = Wh // 2
         eid = atoms.edge
         M = eid.shape[0]

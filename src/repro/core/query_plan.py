@@ -116,13 +116,15 @@ def chunk_atoms(parts: Sequence[AtomSet], cap: int) -> List[AtomSet]:
     return blocks
 
 
-def group_atoms_by_edge(atoms: AtomSet, q_pad: Optional[int] = None):
+def group_atoms_by_edge(atoms: AtomSet, q_pad: Optional[int] = None,
+                        extra: Optional[dict] = None):
     """Route atoms into the per-edge grouped layout the Pallas kernels eat.
 
     Returns (edges [G], packed dict of [G, Qp] host arrays, Qp). ``q_pad``
     overrides the per-group atom capacity (size-class it for jit-cache
     stability); padding rows have ``valid=False``, zero coefficients and
-    empty selection intervals.
+    empty selection intervals. ``extra`` maps names to further per-atom
+    [M, ...] arrays packed the same way (zero padding).
     """
     edges, inv = np.unique(atoms.edge, return_inverse=True)
     G = max(len(edges), 1)
@@ -150,6 +152,8 @@ def group_atoms_by_edge(atoms: AtomSet, q_pad: Optional[int] = None):
         pos_lo2=packed(atoms.pos_lo2, np.inf),
         valid=valid,
     )
+    for k, v in (extra or {}).items():
+        fields[k] = packed(np.asarray(v))
     return edges, fields, Qp
 
 
@@ -159,6 +163,7 @@ def route_atoms_by_shard(
     edge_slot: np.ndarray,
     n_shards: int,
     pad_to: Optional[int] = None,
+    extra: Optional[dict] = None,
 ):
     """Route a plan block's atoms to the shard owning their edge: [S, Mp].
 
@@ -171,7 +176,8 @@ def route_atoms_by_shard(
     and edge slot 0 — they decompose to an empty walk on any shard, so
     routing is safe even for shards that own no atoms.
 
-    Returns a dict of host arrays matching ``jax_engine.FlatAtoms`` fields.
+    Returns a dict of host arrays matching ``jax_engine.FlatAtoms`` fields,
+    plus ``extra``'s per-atom [M, ...] arrays routed the same way.
     Window-independent: one routing serves every query window, exactly like
     the single-host pack.
     """
@@ -195,6 +201,7 @@ def route_atoms_by_shard(
     valid = np.zeros((S, mp), bool)
     for s in range(S):
         valid[s, : counts[s]] = True
+    routed = {k: packed(np.asarray(v)) for k, v in (extra or {}).items()}
     return dict(
         lixel=packed(atoms.lixel),
         edge=packed(edge_slot[atoms.edge]),
@@ -205,6 +212,7 @@ def route_atoms_by_shard(
         lo1_right=packed(atoms.lo1_right, False),
         pos_lo2=packed(atoms.pos_lo2, np.inf),
         valid=valid,
+        **routed,
     )
 
 

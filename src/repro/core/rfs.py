@@ -436,6 +436,17 @@ def make_window_batch(ctx: MomentContext, ts) -> Tuple[np.ndarray, ...]:
     return t_lo, t_hi, lo_right, half, qt
 
 
+def feature_major(x: np.ndarray) -> np.ndarray:
+    """[..., T, 4, K] host moment table → [..., 4K, T] for the device.
+
+    The device keeps moment tables feature-major so the event axis is the
+    minor (lane) axis: a TPU pads a trailing axis of K = 4 values to 128
+    lanes, a 32x blow-up of the largest tables."""
+    x = np.asarray(x)
+    x = x.reshape(x.shape[:-2] + (-1,))
+    return np.ascontiguousarray(np.swapaxes(x, -1, -2))
+
+
 def _device_nbytes(obj) -> int:
     """Total bytes of every device array reachable from ``obj`` — the ONE
     accounting helper for engine tables, atom packs and packed plans
@@ -617,10 +628,16 @@ class _DeviceEngine:
         import jax
         import jax.numpy as jnp
 
+        from ..compat import device_precision, pallas_interpret
         from .query_plan import PlanCache
 
         self._jax = jax
         self._jnp = jnp
+        # every upload and jit call runs in the device path's numeric scope
+        # (f64 on CPU, f32 + full-precision matmuls on an accelerator);
+        # Pallas kernels run interpreted only where the heatmap lives on CPU
+        self._precision = device_precision
+        self._interpret = pallas_interpret
         self._wb_cache = PlanCache(8)
         # op accounting for QueryStats (n_rank_searches / n_moment_gathers /
         # bytes_moved): time-boundary search problems solved, prefix/node
@@ -640,21 +657,21 @@ class _DeviceEngine:
         """Device WindowBatch for the ts tuple, LRU-cached — repeated queries
         over the same centers reuse one device object (and everything keyed
         on it downstream: rank tables, node values, leaf prefixes)."""
-        from .jax_engine import WindowBatch
+        from .jax_engine import WindowBatch, time_key
 
         ts_key = tuple(float(t) for t in ts)
         hit = self._wb_cache.get(ts_key)
         if hit is not None:
             return hit
         t_lo, t_hi, lo_right, half, qt = make_window_batch(ctx, ts)
-        jnp = self._jnp
-        with self._jax.experimental.enable_x64():
+        up = self._upload
+        with self._precision():
             wb = WindowBatch(
-                t_lo=jnp.asarray(t_lo),
-                t_hi=jnp.asarray(t_hi),
-                lo_right=jnp.asarray(lo_right),
-                half=jnp.asarray(half),
-                qt=jnp.asarray(qt),
+                t_lo=up(time_key(t_lo)),
+                t_hi=up(time_key(t_hi)),
+                lo_right=up(lo_right),
+                half=up(half),
+                qt=up(qt),
             )
         self._wb_cache.put(ts_key, wb)
         return wb
@@ -665,8 +682,14 @@ class _DeviceEngine:
         reuses its storage), so a multi-block flush recycles one buffer
         instead of allocating per block — callers must always rebind
         ``heat = flush(... heat ...)`` and never touch the old binding."""
-        with self._jax.experimental.enable_x64():
-            return self._jnp.zeros((n_lixels, n_windows))
+        with self._precision():
+            return self._upload(np.zeros((n_lixels, n_windows)))
+
+    def _upload(self, x):
+        """Host array → device array on this engine's placement: the
+        default device here; the sharded engines replicate over their
+        mesh, so no per-query input sits on the first device only."""
+        return self._jnp.asarray(x)
 
     def _pad_atoms(self, atoms: AtomSet, sel: np.ndarray):
         """Pad the selected atoms to their ⅛-octave size class: FlatAtoms."""
@@ -770,7 +793,7 @@ class FlatForestEngine(_DeviceEngine):
     def _get_flat_forest(self):
         if self._flat is not None:
             return self._flat
-        from .jax_engine import FlatForest
+        from .jax_engine import FlatForest, time_key
 
         rf, jnp = self.rf, self._jnp
 
@@ -781,14 +804,14 @@ class FlatForestEngine(_DeviceEngine):
             return np.full((1,) + x.shape[1:], fill, x.dtype)
 
         bridge = rf.bridge if rf.bridge is not None else np.zeros(1, np.int32)
-        with self._jax.experimental.enable_x64():
+        with self._precision():
             self._flat = FlatForest(
                 pos_flat=jnp.asarray(pad1(rf.pos_flat, np.inf)),
                 cum_flat=jnp.asarray(pad1(rf.cum_flat, 0.0)),
                 edge_base=jnp.asarray(rf.edge_base[:-1]),
                 n_pad=jnp.asarray(rf.n_pad),
                 n_lev=jnp.asarray(rf.n_levels),
-                time_flat=jnp.asarray(pad1(rf.ee.time, np.inf)),
+                time_flat=jnp.asarray(time_key(pad1(rf.ee.time, np.inf))),
                 time_ptr=jnp.asarray(rf.ee.ptr),
                 bridge=jnp.asarray(pad1(bridge, 0)),
             )
@@ -797,19 +820,19 @@ class FlatForestEngine(_DeviceEngine):
     def _get_packed_forest(self):
         if self._packed is not None:
             return self._packed
-        from .jax_engine import PackedForest
+        from .jax_engine import PackedForest, time_key
 
         jnp = self._jnp
         host = build_packed_host_tables(self.rf)
         # build-time codec validation against the f64 host prefix moments:
         # a codec that can't round-trip this forest degrades to f64 in place
         self.codec.validate(host["pm_cum"])
-        with self._jax.experimental.enable_x64():
+        with self._precision():
             pf = PackedForest(
                 pm_pos=jnp.asarray(host["pm_pos"]),
                 pos_base=jnp.asarray(host["pos_base"]),
-                pm_time=jnp.asarray(host["pm_time"]),
-                pm_cum=jnp.asarray(host["pm_cum"]),
+                pm_time=jnp.asarray(time_key(host["pm_time"])),
+                pm_cum=jnp.asarray(feature_major(host["pm_cum"])),
                 edge_base=jnp.asarray(host["edge_base"]),
                 n_pad=jnp.asarray(host["n_pad"]),
                 n_lev=jnp.asarray(host["n_lev"]),
@@ -862,18 +885,20 @@ class FlatForestEngine(_DeviceEngine):
         if hit is not None:
             return hit
         packs = []
-        for atoms in plan.blocks:
+        if self.executor == "fused":
+            # one launch per npad class for the whole plan: per-block packs
+            # would give every block its own (G, Q) launch shapes — one
+            # compile each, hundreds at full Table-3 scale
+            packs = self._fused_pack(AtomSet.concat(plan.blocks))
+        for atoms in plan.blocks if self.executor != "fused" else ():
             if self.executor == "pallas":
                 packs.extend(self._pallas_pack(atoms))
-                continue
-            if self.executor == "fused":
-                packs.extend(self._fused_pack(atoms))
                 continue
             nl = self.rf.n_levels[atoms.edge]
             cls = np.minimum(-(-nl // 3) * 3, self.max_levels).astype(np.int64)
             for c in np.unique(cls):
                 sel = np.nonzero(cls == c)[0]
-                with self._jax.experimental.enable_x64():
+                with self._precision():
                     fa = self._pad_atoms(atoms, sel)
                     entry = dict(max_levels=int(c), fa=fa, m=len(sel))
                     if self.executor == "packed":
@@ -911,7 +936,7 @@ class FlatForestEngine(_DeviceEngine):
                 hi = lo + lvl * p_i
                 pos_g[g] = rf.pos_flat[lo:hi].reshape(lvl, p_i)
                 cum_g[g] = rf.cum_flat[lo:hi].reshape(lvl, p_i, K4)
-            with self._jax.experimental.enable_x64():
+            with self._precision():
                 entries.append(
                     dict(
                         kind="pallas",
@@ -927,11 +952,12 @@ class FlatForestEngine(_DeviceEngine):
         return entries
 
     def _fused_pack(self, atoms):
-        """Per-edge grouped packed-plan layout for the fused executor: one
-        entry per NPAD size class (so every group in a launch shares its
-        node-row count), with the window-independent root rank intervals
-        searched once per plan and cached on the entry — the fused kernel's
-        only remaining inputs are the ts-keyed grouped node values."""
+        """Per-edge grouped packed-plan layout for the fused executor over a
+        plan's atoms: one entry per NPAD size class (so every group in a
+        launch shares its node-row count), with the window-independent root
+        rank intervals searched once per plan and cached on the entry — the
+        fused kernel's only remaining inputs are the ts-keyed grouped node
+        values."""
         from .jax_engine import FlatAtoms
         from .query_plan import group_atoms_by_edge
 
@@ -954,7 +980,7 @@ class FlatForestEngine(_DeviceEngine):
                 offs.append(o)
                 o += p_i >> lev
             G = len(edges)
-            with self._jax.experimental.enable_x64():
+            with self._precision():
                 d_fields = {k: jnp.asarray(v) for k, v in fields.items()}
                 edge2 = np.broadcast_to(
                     edges[:, None], fields["lixel"].shape
@@ -1001,7 +1027,7 @@ class FlatForestEngine(_DeviceEngine):
             return hit
         W = len(ts_key)
         K = self.rf.ctx.K
-        with self._jax.experimental.enable_x64():
+        with self._precision():
             if self.executor in ("packed", "fused"):
                 pk = self._get_packed_forest()
                 tables_fn, _, _ = _get_packed()
@@ -1014,10 +1040,12 @@ class FlatForestEngine(_DeviceEngine):
                 nn = max(pk["n_nodes"], 1)
                 self.counters["rank_searches"] += 3 * W * nn
                 self.counters["moment_gathers"] += 3 * W * nn
-                # fold gathers read paired raw-Φ prefix rows from the f64
-                # host-layout tables (the codec shrinks only the DERIVED
-                # window tables the per-atom walk gathers from)
-                self.counters["bytes_moved"] += 3 * W * nn * N_COMBOS * K * 8
+                # fold gathers read paired raw-Φ prefix rows from the
+                # uncompressed level tables (the codec shrinks only the
+                # DERIVED window tables the per-atom walk gathers from)
+                self.counters["bytes_moved"] += (
+                    3 * W * nn * N_COMBOS * K * self.codec.float_itemsize
+                )
             else:
                 _, ranks_fn = _get_flush()
                 tabs = ranks_fn(
@@ -1048,7 +1076,7 @@ class FlatForestEngine(_DeviceEngine):
         row_bytes = W * 2 * k_s * self.codec.fold_itemsize
         for bi, entry in enumerate(packs):
             c, m = entry["max_levels"], entry["m"]
-            with self._jax.experimental.enable_x64():
+            with self._precision():
                 if self.executor == "packed":
                     pk = self._packed
                     _, _, flush_fn = _get_packed()
@@ -1059,8 +1087,6 @@ class FlatForestEngine(_DeviceEngine):
                     self.counters["moment_gathers"] += 2 * c * m
                     self.counters["bytes_moved"] += 2 * c * m * row_bytes
                 elif self.executor == "fused":
-                    from ..kernels.ops import INTERPRET
-
                     group_fn, fused_flush, _ = _get_fused()
                     gkey = (ts_key, self.codec.name, plan.key, bi)
                     nv_g = self._group_cache.get(gkey)
@@ -1074,7 +1100,7 @@ class FlatForestEngine(_DeviceEngine):
                     heat = fused_flush(
                         nv_g, entry["r_lo"], entry["r_hi"], entry["fields"],
                         heat, offs=entry["offs"], tq=entry["tq"],
-                        interpret=INTERPRET,
+                        interpret=self._interpret(heat),
                     )
                     # ONE kernel launch answered the whole flush; the walk
                     # still touches the same node rows, now codec-sized
@@ -1082,17 +1108,16 @@ class FlatForestEngine(_DeviceEngine):
                     self.counters["moment_gathers"] += 2 * c * m
                     self.counters["bytes_moved"] += 2 * c * m * row_bytes
                 elif self.executor == "pallas":
-                    from ..kernels.ops import INTERPRET
-
                     rfs_flush, _, _ = _get_pallas()
                     heat = rfs_flush(
                         entry["pos"], entry["cum"], tabs, entry["edges"],
                         entry["fields"], wb, heat,
-                        tq=entry["tq"], interpret=INTERPRET,
+                        tq=entry["tq"], interpret=self._interpret(heat),
                     )
                     self.counters["moment_gathers"] += 4 * 2 * W * m * c
                     self.counters["bytes_moved"] += (
-                        4 * 2 * W * m * c * N_COMBOS * K * 8
+                        4 * 2 * W * m * c * N_COMBOS * K
+                        * self.codec.float_itemsize
                     )
                 else:
                     flush_fn, _ = _get_flush()
@@ -1110,16 +1135,18 @@ class FlatForestEngine(_DeviceEngine):
                         2 * 3 * W * m * (c + 1) if cascade else 4 * 2 * W * m * c
                     )
                     self.counters["moment_gathers"] += gathers
-                    self.counters["bytes_moved"] += gathers * 2 * K * 8
+                    self.counters["bytes_moved"] += (
+                        gathers * 2 * K * self.codec.float_itemsize
+                    )
         return heat
 
 
 # ------------------------------------------------------------------- DRFS
 _JIT_DYN = None  # persistent dynamic-engine jit cache: (tables, flush) pair.
-# Keyed on the (size class, Wh, L, Np·Lv) shapes plus the static (n_levels,
-# hq, search/scan/pend trip counts, exact) — steady-state streaming never
-# recompiles because Np / Pp are padded to size classes and trip counts to
-# powers of two.
+# Keyed on the (size class, Wh, L, Np·Lv, Pp) shapes plus the static
+# (n_levels, hq, exact) — steady-state streaming never recompiles: Np is
+# padded to a size class, the pending capacity Pp is fixed per sealed epoch
+# (pending_capacity), and trip counts are traced arguments.
 
 
 def _get_dyn():
@@ -1131,8 +1158,10 @@ def _get_dyn():
 
         from .jax_engine import dyn_node_tables, dyn_window_tables, eval_atoms_dyn
 
+        # trip counts (search_steps / scan_steps / pend_steps) are traced
+        # arguments: occupancies that grow with the stream never recompile
         leaf_tables = functools.partial(
-            jax.jit, static_argnames=("n_levels", "hq", "search_steps", "out_dtype")
+            jax.jit, static_argnames=("n_levels", "hq", "out_dtype")
         )(dyn_window_tables)
         node_tables = functools.partial(
             jax.jit, static_argnames=("n_levels", "hq", "steps_per_level", "out_dtype")
@@ -1140,15 +1169,13 @@ def _get_dyn():
 
         @functools.partial(
             jax.jit,
-            static_argnames=(
-                "n_levels", "hq", "scan_steps", "pend_steps", "exact", "tree"
-            ),
+            static_argnames=("n_levels", "hq", "exact", "tree"),
             donate_argnames=("heat",),
         )
-        def _flush(forest, fa, wb, tables, heat, *, n_levels, hq,
+        def _flush(forest, fa, wb, tables, leaves, heat, *, n_levels, hq,
                    scan_steps, pend_steps, exact, tree=True):
             vals = eval_atoms_dyn(
-                forest, fa, wb, tables,
+                forest, fa, wb, tables, leaves,
                 n_levels=n_levels, hq=hq,
                 scan_steps=scan_steps, pend_steps=pend_steps, exact=exact,
                 tree=tree,
@@ -1175,7 +1202,6 @@ def _get_pallas():
 
         from ..kernels.dyn_query import dyn_leaf_query_pallas, dyn_node_walk_pallas
         from ..kernels.tree_query import tree_query_pallas
-        from .jax_engine import FlatAtoms, _dyn_leaf_range
 
         @functools.partial(
             jax.jit, static_argnames=("tq", "interpret"),
@@ -1184,7 +1210,7 @@ def _get_pallas():
         def _rfs_flush(pos_g, cum_g, ranks, edges, f, wb, heat, *, tq, interpret):
             """Grouped tree_query kernel pass: [G, Wh, Qp] → heat[L, W]."""
             G = pos_g.shape[0]
-            Wh = wb.t_lo.shape[0]
+            Wh = wb.qt.shape[0]
             W = Wh // 2
             Qp = f["qs"].shape[1]
             k_s = f["qs"].shape[-1]
@@ -1224,51 +1250,36 @@ def _get_pallas():
             warm flushes — so the engine caches the result alongside the
             window tables instead of re-gathering it per flush.
             """
-            G = edges.shape[0]
             if exact:
-                (nodeval,) = tables  # [TN·2, W, 2k_s] flat level-major
-                W, C = nodeval.shape[1], nodeval.shape[2]
+                (nodeval,) = tables  # [W·2k_s, 2·TN], level-major node ids
+                TN = nodeval.shape[1] // 2
                 parts = []
                 for d in range(hq + 1):
-                    lo = E * ((1 << d) - 1) * 2
-                    hi = E * ((1 << (d + 1)) - 1) * 2
-                    seg = nodeval[lo:hi].reshape(E, (1 << d) * 2, W, C)
-                    parts.append(seg[edges])
-                nv_g = jnp.concatenate(parts, axis=1)  # [G, R2, W, C]
-                return nv_g.reshape(G, nv_g.shape[1], W * C)
-            (lcum,) = tables  # [E·(nleaf+1)·2, W, 2K]
-            R = (1 << hq) * 2 + 2
-            WK = lcum.shape[1] * lcum.shape[2]
-            return lcum.reshape(E, R, -1)[edges].reshape(G, R, WK)
+                    q = jnp.arange(2 << d)[None, :]
+                    base = E * ((1 << d) - 1) + edges * (1 << d)  # [G]
+                    parts.append(base[:, None] + (q >> 1) + (q & 1) * TN)
+                idx = jnp.concatenate(parts, axis=1)  # [G, R2]
+                return jnp.transpose(nodeval[:, idx], (1, 0, 2))
+            (lcum,) = tables  # [W·2K, 2·E·(nleaf+1)]
+            EL = lcum.shape[1] // 2
+            q = jnp.arange((1 << hq) * 2 + 2)[None, :]  # (leaf, side) pairs
+            idx = edges[:, None] * ((1 << hq) + 1) + (q >> 1) + (q & 1) * EL
+            return jnp.transpose(lcum[:, idx], (1, 0, 2))  # [G, W·2K, R]
 
         @functools.partial(
             jax.jit, static_argnames=("hq", "tq", "interpret", "exact"),
             donate_argnames=("heat",),
         )
-        def _dyn_flush(forest, grouped, edges, f, wb, heat, *, hq, tq,
-                       interpret, exact):
+        def _dyn_flush(grouped, leaves, f, wb, heat, *, hq, tq, interpret, exact):
             """Grouped DRFS kernel pass (tree phase only): scans ride the
-            jnp flush with ``tree=False``."""
+            jnp flush with ``tree=False``. ``leaves`` [G, Qp, 4] holds the
+            host-resolved leaf bounds in the grouped layout."""
             G, Qp = f["pos_hi"].shape
-            W = wb.t_lo.shape[0] // 2
+            W = wb.qt.shape[0] // 2
             k_s = f["qs"].shape[-1]
             k_t = wb.qt.shape[1]
-            edge2 = jnp.broadcast_to(edges[:, None], (G, Qp))
-            fa = FlatAtoms(
-                lixel=f["lixel"].reshape(-1),
-                edge=edge2.reshape(-1),
-                side_feat=f["side_feat"].reshape(-1),
-                qs=f["qs"].reshape(G * Qp, -1),
-                pos_hi=f["pos_hi"].reshape(-1),
-                pos_lo1=f["pos_lo1"].reshape(-1),
-                lo1_right=f["lo1_right"].reshape(-1),
-                pos_lo2=f["pos_lo2"].reshape(-1),
-                valid=f["valid"].reshape(-1),
-            )
-            leaf_lo, leaf_hi = _dyn_leaf_range(forest, fa, hq)
-            leaf_hi = jnp.maximum(leaf_hi, leaf_lo)
-            leaf_lo = leaf_lo.reshape(G, Qp)
-            leaf_hi = leaf_hi.reshape(G, Qp)
+            leaf_lo = leaves[..., 0]
+            leaf_hi = jnp.maximum(leaves[..., 1], leaf_lo)
             qs_m = f["qs"] * f["valid"][..., None]
             if exact:
                 out = dyn_node_walk_pallas(
@@ -1310,31 +1321,29 @@ def _get_fused():
         import jax.numpy as jnp
 
         from ..kernels.fused_walk import fused_leaf_pallas, fused_walk_pallas
-        from .jax_engine import FlatAtoms, _dyn_leaf_range
 
         @functools.partial(jax.jit, static_argnames=("npad", "nlev"))
         def _rfs_group(nodeval, node_base_lvl, edges, *, npad, nlev):
             """Per-edge grouped node values from the flat packed tables.
 
-            The packed build lays an edge's level-ℓ rows contiguously at
-            [node_base[e, ℓ]·2, node_base[e, ℓ]·2 + 2·(npad >> ℓ)), so the
-            fused kernel's [G, R2, W·C] blocks are per-level slices stacked
-            in walk-level order. Depends only on (window tables, plan
-            edges) — both stable across warm flushes — so the engine caches
-            the result alongside the window tables.
+            An edge's level-ℓ nodes are ids [node_base[e, ℓ], + npad >> ℓ)
+            of the [W·C, 2R] table (column side·R + id), so the fused
+            kernel's [G, W·C, R2] blocks are per-level column gathers with
+            the two sides interleaved (block column = node·2 + side),
+            stacked in walk-level order. Depends only on (window tables,
+            plan edges) — both stable across warm flushes — so the engine
+            caches the result alongside the window tables.
             """
-            G = edges.shape[0]
-            W, C = nodeval.shape[1], nodeval.shape[2]
+            R = nodeval.shape[1] // 2
             parts = []
             for lev in range(nlev):
-                nb = npad >> lev
+                q = jnp.arange(2 * (npad >> lev))[None, :]
                 base = jax.lax.dynamic_index_in_dim(
                     node_base_lvl, lev, 0, keepdims=False
                 )[edges]  # [G]
-                idx = base[:, None] * 2 + jnp.arange(nb * 2)[None, :]
-                parts.append(nodeval[idx])  # [G, nb·2, W, C]
-            nv = jnp.concatenate(parts, axis=1)
-            return nv.reshape(G, nv.shape[1], W * C)
+                parts.append(base[:, None] + (q >> 1) + (q & 1) * R)
+            idx = jnp.concatenate(parts, axis=1)  # [G, R2]
+            return jnp.transpose(nodeval[:, idx], (1, 0, 2))
 
         @functools.partial(
             jax.jit, static_argnames=("offs", "tq", "interpret"),
@@ -1358,8 +1367,7 @@ def _get_fused():
             jax.jit, static_argnames=("hq", "tq", "interpret", "exact"),
             donate_argnames=("heat",),
         )
-        def _dyn_flush(forest, grouped, edges, f, wb, heat, *, hq, tq,
-                       interpret, exact):
+        def _dyn_flush(grouped, leaves, f, wb, heat, *, hq, tq, interpret, exact):
             """Fused DRFS tree phase (scans ride the jnp flush, tree=False).
 
             Exact mode runs the complete-tree climb through the SAME fused
@@ -1367,24 +1375,9 @@ def _get_fused():
             starts); quantized mode fuses the q_s ⊗ q_t contraction into the
             stacked leaf-prefix kernel, so only raw per-atom q_s and the
             tiny [W, k_t] temporal vectors cross the launch."""
-            G, Qp = f["pos_hi"].shape
-            W = wb.t_lo.shape[0] // 2
-            edge2 = jnp.broadcast_to(edges[:, None], (G, Qp))
-            fa = FlatAtoms(
-                lixel=f["lixel"].reshape(-1),
-                edge=edge2.reshape(-1),
-                side_feat=f["side_feat"].reshape(-1),
-                qs=f["qs"].reshape(G * Qp, -1),
-                pos_hi=f["pos_hi"].reshape(-1),
-                pos_lo1=f["pos_lo1"].reshape(-1),
-                lo1_right=f["lo1_right"].reshape(-1),
-                pos_lo2=f["pos_lo2"].reshape(-1),
-                valid=f["valid"].reshape(-1),
-            )
-            leaf_lo, leaf_hi = _dyn_leaf_range(forest, fa, hq)
-            leaf_hi = jnp.maximum(leaf_hi, leaf_lo)
-            leaf_lo = leaf_lo.reshape(G, Qp)
-            leaf_hi = leaf_hi.reshape(G, Qp)
+            W = wb.qt.shape[0] // 2
+            leaf_lo = leaves[..., 0]
+            leaf_hi = jnp.maximum(leaves[..., 1], leaf_lo)
             qs_m = f["qs"] * f["valid"][..., None]
             if exact:
                 offs = tuple((1 << (hq - lev)) - 1 for lev in range(hq + 1))
@@ -1442,6 +1435,25 @@ def jit_entry_count() -> int:
             return -1
         total += int(probe())
     return total
+
+
+def pending_capacity(snap, n_pending: int) -> int:
+    """Rows of the device pending buffers for a snapshot: the size class of
+    the auto-seal threshold (``drfs.needs_seal``: pending > sealed / 4), so
+    the buffers keep one shape from one seal to the next and inserts never
+    recompile; a longer backlog (background compaction behind) grows it."""
+    cap = max(snap.n_sealed, 64) // 4 + 1
+    return _size_class(max(int(n_pending), cap), floor=64)
+
+
+def pending_by_position(csr):
+    """Pending CSR (ptr, pos, time, phi) re-sorted by (edge, position): the
+    row order the device pending phase (``jax_engine._pending_moments``)
+    binary-searches. The host keeps (edge, time) order."""
+    pptr, pp, pt, pf = csr
+    edge_of = np.repeat(np.arange(len(pptr) - 1), np.diff(pptr))
+    order = np.lexsort((pp, edge_of))
+    return pptr, pp[order], pt[order], pf[order]
 
 
 class _SealedPack:
@@ -1551,14 +1563,15 @@ class FlatDynamicEngine(_DeviceEngine):
             # codec that can't round-trip this forest degrades to f64
             self.codec.validate(cum_lvl)
             self._codec_checked = True
+        from .jax_engine import time_key
+
         pack = _SealedPack()
-        with self._jax.experimental.enable_x64():
+        with self._precision():
             pack.tables = dict(
-                time_lvl=jnp.asarray(time_lvl),
+                time_lvl=jnp.asarray(time_key(time_lvl)),
                 pos_lvl=jnp.asarray(pos_lvl),
-                cum_lvl=jnp.asarray(cum_lvl),
+                cum_lvl=jnp.asarray(feature_major(cum_lvl)),
                 node_ptr=jnp.asarray(node_ptr),
-                edge_len=jnp.asarray(snap.lens.astype(np.float64)),
             )
         pack.n_levels = Lv
         pack.max_occ = max_occ
@@ -1633,27 +1646,24 @@ class FlatDynamicEngine(_DeviceEngine):
         pack = _PendPack()
         if csr is None:
             pptr = np.zeros(E + 1, np.int64)
-            pp = np.zeros(1)
-            pt = np.full(1, np.inf)
-            pf = np.zeros((1, N_COMBOS, K))
+            pp, pt, pf = np.zeros(0), np.zeros(0), np.zeros((0, N_COMBOS, K))
             pack.pend_steps = 0
         else:
-            pptr, pp, pt, pf = csr
-            Pp = _size_class(len(pp), floor=64)
-            pad = Pp - len(pp)
-            if pad:
-                pp = np.concatenate([pp, np.zeros(pad)])
-                pt = np.concatenate([pt, np.full(pad, np.inf)])
-                pf = np.concatenate([pf, np.zeros((pad,) + pf.shape[1:])])
-            from .aggregation import next_pow2
+            pptr, pp, pt, pf = pending_by_position(csr)
+            pack.pend_steps = int(np.diff(pptr).max(initial=1))
+        Pp = pending_capacity(snap, len(pp))
+        pad = Pp - len(pp)
+        pp = np.concatenate([pp, np.zeros(pad)])
+        pt = np.concatenate([pt, np.full(pad, np.inf)])
+        pf = np.concatenate([pf, np.zeros((pad,) + pf.shape[1:])])
+        from .jax_engine import time_key
 
-            pack.pend_steps = next_pow2(int(np.diff(pptr).max(initial=1)))
-        with self._jax.experimental.enable_x64():
+        with self._precision():
             pack.tables = dict(
                 pend_ptr=jnp.asarray(pptr),
                 pend_pos=jnp.asarray(pp),
-                pend_time=jnp.asarray(pt),
-                pend_phi=jnp.asarray(pf),
+                pend_time=jnp.asarray(time_key(pt)),
+                pend_phi=jnp.asarray(feature_major(pf)),
             )
         pack.nbytes = _device_nbytes(pack.tables)
         self._pend_packs[key] = pack
@@ -1697,7 +1707,7 @@ class FlatDynamicEngine(_DeviceEngine):
         W = len(ts_key)
         K = snap.ctx.K
         forest = self._forest(sealed, self._get_pending(snap))
-        with self._jax.experimental.enable_x64():
+        with self._precision():
             if exact:
                 spl = tuple(steps(o) for o in sealed.max_occ[: hq + 1])
                 tabs = (node_fn(
@@ -1716,8 +1726,10 @@ class FlatDynamicEngine(_DeviceEngine):
                 nn = E * (1 << hq)
             self.counters["rank_searches"] += 3 * W * nn
             self.counters["moment_gathers"] += 3 * W * nn
-            # table folds gather raw-Φ prefix rows from the f64 level tables
-            self.counters["bytes_moved"] += 3 * W * nn * N_COMBOS * K * 8
+            # table folds gather raw-Φ prefix rows from the level tables
+            self.counters["bytes_moved"] += (
+                3 * W * nn * N_COMBOS * K * self.codec.float_itemsize
+            )
         self._tab_cache[key] = tabs
         while len(self._tab_cache) > 4 * self.max_snapshots:
             self._tab_cache.popitem(last=False)
@@ -1737,7 +1749,7 @@ class FlatDynamicEngine(_DeviceEngine):
         jnp = self._jnp
         packs = []
         for atoms in plan.blocks:
-            with self._jax.experimental.enable_x64():
+            with self._precision():
                 entry = dict(fa=self._pad_atoms(atoms, np.arange(atoms.m)),
                              atoms=atoms, m=atoms.m)
                 if self.executor in ("pallas", "fused"):
@@ -1746,10 +1758,37 @@ class FlatDynamicEngine(_DeviceEngine):
                     edges, fields, _ = group_atoms_by_edge(atoms, q_pad=qp)
                     entry["edges"] = jnp.asarray(edges)
                     entry["fields"] = {k: jnp.asarray(v) for k, v in fields.items()}
+                    entry["qp"] = qp
                     entry["tq"] = min(128, qp)
                 packs.append(entry)
         self._pack_cache.put(plan.key, packs)
         return packs
+
+    def _leaf_pack(self, entry, snap, hq: int):
+        """Host-resolved leaf bounds of one atom block at depth ``hq``
+        (``drfs.leaf_bounds``, f64), uploaded once per (plan block, hq):
+        ``flat`` [Mpad, 4] for the jnp flush and, for the kernel executors,
+        ``grouped`` [G, Qp, 4] in the per-edge layout."""
+        key = ("leaves", int(hq))
+        hit = entry.get(key)
+        if hit is not None:
+            return hit
+        from .query_plan import group_atoms_by_edge
+
+        atoms = entry["atoms"]
+        lb = snap.leaf_bounds(atoms, hq).astype(np.int32)  # [m, 4]
+        flat = np.zeros((entry["fa"].valid.shape[0], 4), np.int32)
+        flat[:, 2:] = -1  # padding rows: empty range, no boundary scans
+        flat[: atoms.m] = lb
+        with self._precision():
+            pack = dict(flat=self._jnp.asarray(flat))
+            if "edges" in entry:
+                _, f, _ = group_atoms_by_edge(
+                    atoms, q_pad=entry["qp"], extra={"leaves": lb}
+                )
+                pack["grouped"] = self._jnp.asarray(f["leaves"])
+        entry[key] = pack
+        return pack
 
     def flush_plan(self, heat, plan, wb, ts_key, *, h0=None, exact_leaf=False,
                    snapshot=None, **_):
@@ -1790,6 +1829,7 @@ class FlatDynamicEngine(_DeviceEngine):
         )
         for bi, entry in enumerate(self._atom_packs(plan)):
             atoms = entry["atoms"]
+            leaves = self._leaf_pack(entry, snap, hq)
             # work accounting (same units as the NumPy scans: (atom, event)
             # pairs examined, per half-window for partials / window pending)
             snap.counters["pending"] += snap.pending_scan_pairs(atoms) * W
@@ -1798,11 +1838,9 @@ class FlatDynamicEngine(_DeviceEngine):
             gathers = 2 * (hq + 1) * entry["m"] if exact_leaf else 2 * entry["m"]
             self.counters["moment_gathers"] += gathers
             self.counters["bytes_moved"] += gathers * row_bytes
-            with self._jax.experimental.enable_x64():
+            with self._precision():
                 if self.executor in ("pallas", "fused"):
                     # tree phase on the kernels; scans stay in the jnp flush
-                    from ..kernels.ops import INTERPRET
-
                     _, dyn_flush, dyn_group = _get_pallas()
                     if self.executor == "fused":
                         _, _, dyn_flush = _get_fused()
@@ -1817,13 +1855,13 @@ class FlatDynamicEngine(_DeviceEngine):
                         )
                         self._group_cache.put(gkey, grouped)
                     heat = dyn_flush(
-                        forest, grouped, entry["edges"], entry["fields"], wb,
+                        grouped, leaves["grouped"], entry["fields"], wb,
                         heat, hq=int(hq), tq=entry["tq"],
-                        interpret=INTERPRET, exact=bool(exact_leaf),
+                        interpret=self._interpret(heat), exact=bool(exact_leaf),
                     )
                     if scan_steps or pend.pend_steps:
                         heat = flush_fn(
-                            forest, entry["fa"], wb, (), heat,
+                            forest, entry["fa"], wb, (), leaves["flat"], heat,
                             n_levels=sealed.n_levels,
                             hq=int(hq),
                             scan_steps=int(scan_steps),
@@ -1833,7 +1871,7 @@ class FlatDynamicEngine(_DeviceEngine):
                         )
                 else:
                     heat = flush_fn(
-                        forest, entry["fa"], wb, tables, heat,
+                        forest, entry["fa"], wb, tables, leaves["flat"], heat,
                         n_levels=sealed.n_levels,
                         hq=int(hq),
                         scan_steps=int(scan_steps),

@@ -27,7 +27,8 @@ the index in large vectorized blocks — the same batching the distributed
                   scanned on device so insert -> query never rebuilds)
   engine='pallas' same engines, tree phase routed through the Pallas kernels
   engine='numpy'  the host reference path (one eval_atoms pass per window)
-  engine='auto'   'jax' for rfs/drfs, 'numpy' otherwise / on jax failure
+  engine='auto'   'jax' for rfs/drfs; 'numpy' otherwise, or when JAX is not
+                  installed (any other engine failure raises)
 
 ``executor`` picks the jnp executor flavour over the packed query plan:
 'packed' (gather-lean default), 'cascade' / 'search' (the legacy rfs
@@ -38,7 +39,8 @@ cached for its (epoch, LS) pair — warm queries skip planning entirely —
 and window-side tables cached by the ts tuple (DESIGN.md §7).
 
 ``table_codec`` picks the device window-table layout (jax_engine.TableCodec):
-'auto'/'f64' keeps bit-exact f64 tables; 'f32'/'bf16' shrink the rows the
+'auto'/'f64' keeps the device float (f64 on CPU, f32 on a TPU —
+``compat.device_x64``); 'f32'/'bf16' shrink the rows the
 per-atom walk gathers (build-time validated, falls back to f64 when the
 round-trip exceeds the preset tolerance). QueryStats.bytes_moved measures
 the effect in the same units for every executor.
@@ -170,7 +172,8 @@ class TNKDE:
                 )
             if table_codec not in ("auto", "f64"):
                 raise ValueError(
-                    "the sharded path keeps f64 slabs (table_codec='auto'/'f64')"
+                    "the sharded path keeps uncompressed slabs "
+                    "(table_codec='auto'/'f64')"
                 )
         if lixel_sharing and solution == "sps":
             raise ValueError("lixel sharing needs an aggregation index (ada/rfs/drfs)")
@@ -286,6 +289,18 @@ class TNKDE:
             self.engine = "jax"
         elif solution in ("rfs", "drfs") and engine != "numpy":
             try:
+                import jax  # noqa: F401
+            except ImportError:
+                if engine != "auto":
+                    raise
+                # engine='auto' without JAX installed: the host path is the
+                # only one there is. Every other failure raises — a fallback
+                # that hides a broken device path reports host numbers as
+                # device ones.
+                import warnings
+
+                warnings.warn("jax is not installed, using the numpy path")
+            else:
                 from .rfs import FlatDynamicEngine, FlatForestEngine
 
                 self._fe = (
@@ -302,15 +317,6 @@ class TNKDE:
                     )
                 )
                 self.engine = "pallas" if executor == "pallas" else "jax"
-            except Exception as e:
-                if engine in ("jax", "pallas"):
-                    raise
-                # engine='auto': fall back to the host path, but loudly — a
-                # silent fallback would mask real engine bugs as slowness
-                import warnings
-
-                warnings.warn(f"jax engine unavailable, using numpy path: {e!r}")
-                self._fe = None
         from .query_plan import PlanCache
 
         self._plan_cache = PlanCache(2)

@@ -6,15 +6,16 @@ path:
 
   * :func:`dyn_leaf_query_pallas` — the quantized serving mode over the
     **leaf-prefix layout** (``jax_engine.dyn_window_tables``): per edge a
-    [(nleaf+1)·2, W·2K] table of per-side leaf-prefix moment rows (raw Φ,
-    halves paired, the W axis inside the row). An atom's fully-covered leaf
-    range costs two one-hot row selections (MXU matmuls — the gather-free
-    formulation) and one contraction with the per-half query vectors.
+    feature-major [W·2K, (nleaf+1)·2] table of per-side leaf-prefix moment
+    columns (raw Φ, halves paired, the W axis inside the column). An atom's
+    fully-covered leaf range costs one signed one-hot selection (an MXU
+    matmul — the gather-free formulation) and one contraction with the
+    per-half query vectors.
   * :func:`dyn_node_walk_pallas` — the exact mode over the **node-value
     layout** (``jax_engine.dyn_node_tables`` repacked per edge): the
-    canonical ≤2-nodes-per-level walk accumulates a [TQ, R] one-hot
-    selection matrix over the static level unroll and pays ONE matmul
-    against the q_t-folded node table at the end.
+    canonical ≤2-nodes-per-level walk of ``fused_walk_pallas`` with the
+    complete-tree row offsets — one [TQ, R] selection mask over the static
+    level unroll and ONE matmul against the q_t-folded node table.
 
 Both kernels cover phase 1 (the tree) of ``jax_engine.eval_atoms_dyn``; the
 partial-leaf and pending scans stay in the surrounding jit (they are masked
@@ -29,35 +30,38 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .fused_walk import _NT, _atom_spec, _pad_q, fused_walk_pallas
+from .tree_query import _HIGHEST, _col
+
 __all__ = ["dyn_leaf_query_pallas", "dyn_node_walk_pallas"]
 
 
 def _leaf_kernel(tab_ref, llo_ref, lhi_ref, side_ref, qvl_ref, qvr_ref, o_ref, *, nw, kk):
-    TQ = o_ref.shape[-1]
-    R = tab_ref.shape[1]
+    TQ = o_ref.shape[1]
+    R = tab_ref.shape[2]
     dt = tab_ref.dtype
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, R), 1)  # [1, R]
-    side = side_ref[0, :].astype(jnp.int32)
-    idx_hi = lhi_ref[0, :].astype(jnp.int32) * 2 + side
-    idx_lo = llo_ref[0, :].astype(jnp.int32) * 2 + side
-    tab = tab_ref[0]  # [R, W·2K]
-    oh = (iota == idx_hi[:, None]).astype(dt) - (iota == idx_lo[:, None]).astype(dt)
-    diff = oh @ tab  # [TQ, W·2K] — prefix difference via one matmul
-    diff = diff.reshape(TQ, nw, 2 * kk)
-    vals = []
+    iota = jax.lax.broadcasted_iota(jnp.int32, (TQ, R), 1)
+    side = side_ref[0]  # [TQ, 1]
+    oh = (
+        (iota == lhi_ref[0] * 2 + side).astype(dt)
+        - (iota == llo_ref[0] * 2 + side).astype(dt)
+    )
+    # prefix difference via one matmul: [TQ, R] x [W·2K, R]^T
+    diff = jax.lax.dot_general(oh, tab_ref[0], _NT, precision=_HIGHEST,
+                               preferred_element_type=dt)
     for w in range(nw):
+        b = w * 2 * kk
         qvl = qvl_ref[0, w]  # [TQ, K]
         qvr = qvr_ref[0, w]
-        vals.append(
-            jnp.sum(qvl * diff[:, w, :kk], axis=1)
-            + jnp.sum(qvr * diff[:, w, kk:], axis=1)
+        o_ref[0, :, w : w + 1] = (
+            jnp.sum(qvl * diff[:, b : b + kk], axis=1, keepdims=True)
+            + jnp.sum(qvr * diff[:, b + kk : b + 2 * kk], axis=1, keepdims=True)
         )
-    o_ref[0, :, :] = jnp.stack(vals)
 
 
 @functools.partial(jax.jit, static_argnames=("tq", "interpret"))
 def dyn_leaf_query_pallas(
-    tab: jnp.ndarray,  # [G, (nleaf+1)·2, W·2K] per-edge leaf-prefix tables
+    tab: jnp.ndarray,  # [G, W·2K, (nleaf+1)·2] per-edge leaf-prefix tables
     leaf_lo: jnp.ndarray,  # [G, Q] fully-covered leaf range lo (i32)
     leaf_hi: jnp.ndarray,  # [G, Q] leaf range hi
     side: jnp.ndarray,  # [G, Q] event-feature side in {0, 1}
@@ -69,84 +73,34 @@ def dyn_leaf_query_pallas(
 ) -> jnp.ndarray:
     """Quantized DRFS tree phase over the leaf-prefix layout: [G, W, Q],
     halves already folded per window center. Runs in the input dtype."""
-    G, R, WK = tab.shape
+    G, WK, R = tab.shape
     W, Q, K = qv_l.shape[1], qv_l.shape[2], qv_l.shape[3]
     tq = min(tq, Q) or 1
     qp = -(-Q // tq) * tq
 
-    def padq(x, fill=0):
-        out = jnp.full(x.shape[:-1] + (qp,), fill, x.dtype)
-        return out.at[..., :Q].set(x)
+    def padq_w(x):  # [G, W, Q, K]: pad the atom axis
+        return jnp.pad(x.astype(tab.dtype), ((0, 0), (0, 0), (0, qp - Q), (0, 0)))
 
-    def padq_t(x):
-        out = jnp.zeros(x.shape[:-2] + (qp, x.shape[-1]), x.dtype)
-        return out.at[..., :Q, :].set(x)
-
+    ints = [_col(_pad_q(x.astype(jnp.int32), qp)) for x in (leaf_lo, leaf_hi, side)]
     out = pl.pallas_call(
         functools.partial(_leaf_kernel, nw=W, kk=K),
         grid=(G, qp // tq),
         in_specs=[
-            pl.BlockSpec((1, R, WK), lambda g, q: (g, 0, 0)),
-            pl.BlockSpec((1, tq), lambda g, q: (g, q)),
-            pl.BlockSpec((1, tq), lambda g, q: (g, q)),
-            pl.BlockSpec((1, tq), lambda g, q: (g, q)),
+            pl.BlockSpec((1, WK, R), lambda g, q: (g, 0, 0)),
+            _atom_spec(tq), _atom_spec(tq), _atom_spec(tq),
             pl.BlockSpec((1, W, tq, K), lambda g, q: (g, 0, q, 0)),
             pl.BlockSpec((1, W, tq, K), lambda g, q: (g, 0, q, 0)),
         ],
-        out_specs=pl.BlockSpec((1, W, tq), lambda g, q: (g, 0, q)),
-        out_shape=jax.ShapeDtypeStruct((G, W, qp), tab.dtype),
+        out_specs=_atom_spec(tq, W),
+        out_shape=jax.ShapeDtypeStruct((G, qp, W), tab.dtype),
         interpret=interpret,
-    )(
-        tab,
-        padq(leaf_lo.astype(jnp.int32)),
-        padq(leaf_hi.astype(jnp.int32)),
-        padq(side.astype(jnp.int32)),
-        padq_t(qv_l.astype(tab.dtype)),
-        padq_t(qv_r.astype(tab.dtype)),
-    )
-    return out[:, :, :Q]
-
-
-def _walk_kernel(nv_ref, rlo_ref, rhi_ref, side_ref, qs_ref, o_ref, *, hq, nw, ks):
-    TQ = o_ref.shape[-1]
-    R2 = nv_ref.shape[1]
-    dt = nv_ref.dtype
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, R2), 1)  # [1, R2]
-    side = side_ref[0, :].astype(jnp.int32)
-    l = rlo_ref[0, :].astype(jnp.int32)
-    r = rhi_ref[0, :].astype(jnp.int32)
-    sel = jnp.zeros((TQ, R2), dt)
-    # canonical ≤2-nodes-per-level climb, statically unrolled; walk level
-    # ``lev`` reads depth d = hq − lev whose within-edge block starts at
-    # row (2^d − 1)·2 (matches the per-edge repack of dyn_node_tables)
-    for lev in range(hq + 1):
-        off = (1 << (hq - lev)) - 1
-        active = l < r
-        emit_l = active & ((l & 1) == 1)
-        row_l = (off + l) * 2 + side
-        sel = sel + jnp.where(
-            emit_l[:, None], (iota == row_l[:, None]).astype(dt), 0.0
-        )
-        l = jnp.where(emit_l, l + 1, l)
-        emit_r = (l < r) & ((r & 1) == 1)
-        row_r = (off + r - 1) * 2 + side
-        sel = sel + jnp.where(
-            emit_r[:, None], (iota == row_r[:, None]).astype(dt), 0.0
-        )
-        r = jnp.where(emit_r, r - 1, r)
-        l, r = l >> 1, r >> 1
-    acc = sel @ nv_ref[0]  # [TQ, W·2k_s] — the whole walk in one matmul
-    acc = acc.reshape(TQ, nw, 2 * ks)
-    qs = qs_ref[0]  # [TQ, k_s]
-    vals = [
-        jnp.sum(qs * (acc[:, w, :ks] + acc[:, w, ks:]), axis=1) for w in range(nw)
-    ]
-    o_ref[0, :, :] = jnp.stack(vals)
+    )(tab, *ints, padq_w(qv_l), padq_w(qv_r))
+    return jnp.transpose(out[:, :Q], (0, 2, 1))
 
 
 @functools.partial(jax.jit, static_argnames=("hq", "tq", "interpret"))
 def dyn_node_walk_pallas(
-    nodeval: jnp.ndarray,  # [G, (2^{hq+1}−1)·2, W·2k_s] per-edge node values
+    nodeval: jnp.ndarray,  # [G, W·2k_s, (2^{hq+1}−1)·2] per-edge node values
     r_lo: jnp.ndarray,  # [G, Q] fully-covered leaf range lo
     r_hi: jnp.ndarray,  # [G, Q]
     side: jnp.ndarray,  # [G, Q]
@@ -157,40 +111,11 @@ def dyn_node_walk_pallas(
     interpret: bool = True,
 ) -> jnp.ndarray:
     """Exact-mode DRFS tree phase over q_t-folded node values: [G, W, Q],
-    halves folded. The per-atom canonical walk builds a one-hot selection
-    matrix and the node gathers collapse into one MXU matmul."""
-    G, R2, WC = nodeval.shape
-    Q, ks = qs.shape[1], qs.shape[2]
-    W = WC // (2 * ks)
-    tq = min(tq, Q) or 1
-    qp = -(-Q // tq) * tq
-
-    def padq(x, fill=0):
-        out = jnp.full(x.shape[:-1] + (qp,), fill, x.dtype)
-        return out.at[..., :Q].set(x)
-
-    def padq_t(x):
-        out = jnp.zeros(x.shape[:-2] + (qp, x.shape[-1]), x.dtype)
-        return out.at[..., :Q, :].set(x)
-
-    out = pl.pallas_call(
-        functools.partial(_walk_kernel, hq=hq, nw=W, ks=ks),
-        grid=(G, qp // tq),
-        in_specs=[
-            pl.BlockSpec((1, R2, WC), lambda g, q: (g, 0, 0)),
-            pl.BlockSpec((1, tq), lambda g, q: (g, q)),
-            pl.BlockSpec((1, tq), lambda g, q: (g, q)),
-            pl.BlockSpec((1, tq), lambda g, q: (g, q)),
-            pl.BlockSpec((1, tq, ks), lambda g, q: (g, q, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, W, tq), lambda g, q: (g, 0, q)),
-        out_shape=jax.ShapeDtypeStruct((G, W, qp), nodeval.dtype),
-        interpret=interpret,
-    )(
-        nodeval,
-        padq(r_lo.astype(jnp.int32)),
-        padq(r_hi.astype(jnp.int32)),
-        padq(side.astype(jnp.int32)),
-        padq_t(qs.astype(nodeval.dtype)),
+    halves folded. Walk level ``lev`` reads depth d = hq − lev, whose
+    within-edge block starts at node row 2^d − 1 (the per-edge repack of
+    ``dyn_node_tables``); runs in the input dtype."""
+    offs = tuple((1 << (hq - lev)) - 1 for lev in range(hq + 1))
+    return fused_walk_pallas(
+        nodeval, r_lo, r_hi, side, qs,
+        offs=offs, tq=tq, interpret=interpret, precise=True,
     )
-    return out[:, :, :Q]

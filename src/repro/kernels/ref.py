@@ -111,7 +111,7 @@ def tree_query(
 
 
 def dyn_leaf_query(
-    tab: jnp.ndarray,  # [G, (nleaf+1)·2, W·2K] per-edge leaf-prefix tables
+    tab: jnp.ndarray,  # [G, W·2K, (nleaf+1)·2] per-edge leaf-prefix tables
     leaf_lo: jnp.ndarray,  # [G, Q]
     leaf_hi: jnp.ndarray,  # [G, Q]
     side: jnp.ndarray,  # [G, Q] in {0, 1}
@@ -125,6 +125,7 @@ def dyn_leaf_query(
     the last axis, W inside the row), contracted with the per-half query
     vectors and folded per window center.
     """
+    tab = jnp.swapaxes(tab, 1, 2)  # kernels take [G, W·C, R]
     G, R, WK = tab.shape
     W, Q, K = qv_l.shape[1], qv_l.shape[2], qv_l.shape[3]
     gi = jnp.arange(G)[:, None]
@@ -137,7 +138,7 @@ def dyn_leaf_query(
 
 
 def dyn_node_walk(
-    nodeval: jnp.ndarray,  # [G, (2^{hq+1}−1)·2, W·2k_s] per-edge node values
+    nodeval: jnp.ndarray,  # [G, W·2k_s, (2^{hq+1}−1)·2] per-edge node values
     r_lo: jnp.ndarray,  # [G, Q] fully-covered leaf range lo
     r_hi: jnp.ndarray,  # [G, Q]
     side: jnp.ndarray,  # [G, Q]
@@ -147,6 +148,7 @@ def dyn_node_walk(
 ) -> jnp.ndarray:
     """Exact-mode DRFS tree phase: canonical walk over q_t-folded node
     values, halves folded per window center: [G, W, Q]."""
+    nodeval = jnp.swapaxes(nodeval, 1, 2)  # kernels take [G, W·C, R]
     G, R2, WC = nodeval.shape
     Q, ks = qs.shape[1], qs.shape[2]
     W = WC // (2 * ks)
@@ -176,7 +178,7 @@ def dyn_node_walk(
 
 
 def fused_walk(
-    nodeval: jnp.ndarray,  # [G, R2, W·2k_s] per-edge q_t-folded node values
+    nodeval: jnp.ndarray,  # [G, W·2k_s, R2] per-edge q_t-folded node values
     r_lo: jnp.ndarray,  # [G, Q] root rank interval lo
     r_hi: jnp.ndarray,  # [G, Q]
     side: jnp.ndarray,  # [G, Q]
@@ -193,6 +195,7 @@ def fused_walk(
     forest layout (level-major, offs[ℓ] = Σ_{j<ℓ} npad>>j) and the DRFS
     complete tree (offs[ℓ] = 2^{hq−ℓ} − 1).
     """
+    nodeval = jnp.swapaxes(nodeval, 1, 2)  # kernels take [G, W·C, R]
     G, R2, WC = nodeval.shape
     Q, ks = qs.shape[1], qs.shape[2]
     W = WC // (2 * ks)
@@ -223,7 +226,7 @@ def fused_walk(
 
 
 def fused_leaf(
-    lcum: jnp.ndarray,  # [G, (nleaf+1)·2, W·2K] per-edge leaf-prefix tables
+    lcum: jnp.ndarray,  # [G, W·2K, (nleaf+1)·2] per-edge leaf-prefix tables
     leaf_lo: jnp.ndarray,  # [G, Q]
     leaf_hi: jnp.ndarray,  # [G, Q]
     side: jnp.ndarray,  # [G, Q]
@@ -234,6 +237,7 @@ def fused_leaf(
     """Quantized DRFS tree phase with the q_s ⊗ q_t contraction fused in:
     [G, W, Q], halves folded — :func:`dyn_leaf_query` semantics consuming
     the raw factored query instead of materialized per-window vectors."""
+    lcum = jnp.swapaxes(lcum, 1, 2)  # kernels take [G, W·C, R]
     G, R, WK = lcum.shape
     Q, ks = qs.shape[1], qs.shape[2]
     W, kt = qtl.shape[0], qtl.shape[1]

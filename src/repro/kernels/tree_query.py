@@ -14,16 +14,18 @@ TPU-native choices (vs the CPU pointer walk):
   * the *prefix-moment gather* becomes a **one-hot × table matmul** on the
     MXU: onehot(i-1) @ cum_level  ([TQ, NPAD] @ [NPAD, K]).
   * one grid step owns one edge-group's whole table (BlockSpec brings
-    [LVL, NPAD(, K)] into VMEM) and a TQ-tile of its queries, so the level
-    and window loops are static Python unrolls.
+    [LVL, NPAD(, K)] into VMEM) and a TQ-tile of its queries; the level
+    loop is an in-kernel ``fori_loop`` (a static unroll of levels × windows
+    did not compile in minutes at NPAD = 2048) and the window loop a static
+    unroll inside it.
 
 Window batching (DESIGN.md §4): the W axis carries the per-window time-rank
 intervals and temporal-weighted query vectors; the position bounds are per
-query only. Per level the three compare masks and their per-bucket
-segment-counts (one [TQ, NPAD] @ [NPAD, NB] matmul each) are computed
-**once** and shared by every window — each window then pays only one-hot
-count gathers and the two prefix-moment matmuls for its own <= 2 buckets.
-That is the hoist that makes the per-window cost shrink as W grows.
+query only. Per level the three compare masks are computed **once** and
+shared by every window — each window then pays only the masked in-bucket
+counts and one signed one-hot prefix-moment matmul for each of its <= 2
+buckets. That is the hoist that makes the per-window cost shrink as W
+grows.
 
 Callers bucket edges into groups of uniform padded size NPAD (size-classed
 batching) — see repro.core.distributed.
@@ -39,65 +41,74 @@ from jax.experimental import pallas as pl
 __all__ = ["tree_query_pallas"]
 
 
+_HIGHEST = jax.lax.Precision.HIGHEST  # f32 one-hot selections stay exact
+
+
+def _col(x):
+    """[G, Q] → [G, Q, 1]. Per-atom fields enter the kernels as (1, tq, 1)
+    blocks, so each is a [TQ, 1] column that broadcasts against [TQ, R]
+    tiles: the TPU tiling refuses a (1, tq) block over [G, Q], and Mosaic
+    cannot recast an in-kernel (TQ,) vector to (TQ, 1)."""
+    return x[..., None]
+
+
 def _kernel(pos_ref, cum_ref, rlo_ref, rhi_ref, bnd_ref, l1r_ref, qv_ref, o_ref, *, lvl, npad, nw):
-    TQ = o_ref.shape[-1]
+    TQ = o_ref.shape[1]
     dt = cum_ref.dtype  # f32 on TPU; f64 when the engine runs interpret mode
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, npad), 1)  # [1, NPAD]
-    ph = bnd_ref[0, :, 0]
-    pl1 = bnd_ref[0, :, 1]
-    pl2 = bnd_ref[0, :, 2]
-    l1r = l1r_ref[0, :] != 0
-    ls = [rlo_ref[0, w, :].astype(jnp.int32) for w in range(nw)]  # each [TQ]
-    rs = [rhi_ref[0, w, :].astype(jnp.int32) for w in range(nw)]
-    accs = [jnp.zeros((TQ,), dt) for _ in range(nw)]
+    f32 = jnp.float32
+    iota = jax.lax.broadcasted_iota(jnp.int32, (TQ, npad), 1)  # [TQ, NPAD]
+    bnd = bnd_ref[0]  # [TQ, 3] position bounds (hi, lo1, lo2)
+    ph, pl1, pl2 = bnd[:, 0:1], bnd[:, 1:2], bnd[:, 2:3]  # [TQ, 1] columns
+    l1r = l1r_ref[0] != 0
+    qvs = [qv_ref[0, w] for w in range(nw)]  # each [TQ, K]
 
-    for lev in range(lvl):
-        p_row = pos_ref[0, lev, :]  # [NPAD]
-        c_lvl = cum_ref[0, lev, :, :]  # [NPAD, K]
-        nb = npad >> lev
-        pr = p_row[None, :]
-        # ---- window-independent: compare masks + per-bucket counts (hoisted)
-        m_hi = (pr <= ph[:, None]).astype(jnp.float32)  # [TQ, NPAD]
-        m_l1 = jnp.where(
-            l1r[:, None], pr <= pl1[:, None], pr < pl1[:, None]
-        ).astype(jnp.float32)
-        m_l2 = (pr < pl2[:, None]).astype(jnp.float32)
-        iota_b = jax.lax.broadcasted_iota(jnp.int32, (1, nb), 1)  # [1, NB]
-        seg = ((iota.reshape(npad, 1) >> lev) == iota_b).astype(jnp.float32)  # [NPAD, NB]
-        cnt_hi = m_hi @ seg  # [TQ, NB] segment compare-counts (MXU)
-        cnt_l1 = m_l1 @ seg
-        cnt_l2 = m_l2 @ seg
+    def level(lev, carry):
+        ls, rs, accs = (list(c) for c in carry)
+        pr = pos_ref[0, pl.ds(lev, 1), :]  # [1, NPAD]
+        c_lvl = cum_ref[0, lev]  # [NPAD, K]
+        # ---- window-independent: compare masks (hoisted over windows) -----
+        m_hi = (pr <= ph).astype(f32)  # [TQ, NPAD]
+        m_l1 = jnp.where(l1r, (pr <= pl1).astype(f32), (pr < pl1).astype(f32))
+        m_l2 = (pr < pl2).astype(f32)
+        bucket = iota >> lev
 
-        # ---- per-window: canonical climb using the shared counts ----------
+        def bucket_val(b, on, qv):
+            inb = (bucket == b).astype(f32)  # bucket b's slots of the row
+            seg_lo = b << lev
+            i_hi = seg_lo + jnp.sum(inb * m_hi, axis=1, keepdims=True).astype(jnp.int32)
+            c_l1 = jnp.sum(inb * m_l1, axis=1, keepdims=True).astype(jnp.int32)
+            c_l2 = jnp.sum(inb * m_l2, axis=1, keepdims=True).astype(jnp.int32)
+            i_lo = seg_lo + jnp.maximum(c_l1, c_l2)
+            i_hi = jnp.maximum(i_hi, i_lo)
+            # prefix(i_hi) − prefix(i_lo) in ONE signed one-hot matmul (MXU)
+            oh = (
+                ((iota == i_hi - 1) & (i_hi > seg_lo)).astype(dt)
+                - ((iota == i_lo - 1) & (i_lo > seg_lo)).astype(dt)
+            )
+            mom = jnp.dot(oh, c_lvl, precision=_HIGHEST, preferred_element_type=dt)
+            return jnp.where(on, jnp.sum(qv * mom, axis=1, keepdims=True), 0.0)
+
+        # ---- per-window: canonical climb over the shared masks --------------
         for w in range(nw):
             l, r = ls[w], rs[w]
-            qv = qv_ref[0, w, :, :]  # [TQ, K]
             active = l < r
-
-            def bucket_val(b, on):
-                ohb = (iota_b == b[:, None]).astype(jnp.float32)  # [TQ, NB]
-                seg_lo = b << lev
-                i_hi = seg_lo + jnp.sum(ohb * cnt_hi, axis=1).astype(jnp.int32)
-                c_l1 = jnp.sum(ohb * cnt_l1, axis=1).astype(jnp.int32)
-                c_l2 = jnp.sum(ohb * cnt_l2, axis=1).astype(jnp.int32)
-                i_lo = seg_lo + jnp.maximum(c_l1, c_l2)
-                i_hi = jnp.maximum(i_hi, i_lo)
-
-                def pref(i):
-                    oh = (iota == (i - 1)[:, None]) & (i > seg_lo)[:, None]
-                    return oh.astype(dt) @ c_lvl  # [TQ, K] (MXU)
-
-                mom = pref(i_hi) - pref(i_lo)
-                return jnp.where(on, jnp.sum(qv * mom, axis=1), 0.0)
-
             emit_l = active & ((l & 1) == 1)
-            accs[w] = accs[w] + bucket_val(l, emit_l)
+            accs[w] = accs[w] + bucket_val(l, emit_l, qvs[w])
             l = jnp.where(emit_l, l + 1, l)
             emit_r = (l < r) & ((r & 1) == 1)
-            accs[w] = accs[w] + bucket_val(r - 1, emit_r)
+            accs[w] = accs[w] + bucket_val(r - 1, emit_r, qvs[w])
             r = jnp.where(emit_r, r - 1, r)
             ls[w], rs[w] = l >> 1, r >> 1
-    o_ref[0, :, :] = jnp.stack(accs)
+        return tuple(ls), tuple(rs), tuple(accs)
+
+    init = (
+        tuple(rlo_ref[0, :, w : w + 1] for w in range(nw)),  # each [TQ, 1]
+        tuple(rhi_ref[0, :, w : w + 1] for w in range(nw)),
+        tuple(jnp.zeros((TQ, 1), dt) for _ in range(nw)),
+    )
+    _, _, accs = jax.lax.fori_loop(0, lvl, level, init)
+    for w in range(nw):
+        o_ref[0, :, w : w + 1] = accs[w]
 
 
 @functools.partial(jax.jit, static_argnames=("tq", "interpret", "precise"))
@@ -129,40 +140,41 @@ def tree_query_pallas(
     tq = min(tq, Q) or 1
     qp = -(-Q // tq) * tq
 
-    def padq(x, fill=0):
-        out = jnp.full(x.shape[:-1] + (qp,), fill, x.dtype)
-        return out.at[..., :Q].set(x)
+    def padq(x):  # pad axis 1 (atoms) of [G, Q, ...]
+        pad = [(0, 0)] * x.ndim
+        pad[1] = (0, qp - Q)
+        return jnp.pad(x, pad)
 
-    def padq_t(x, fill=0.0):  # pad axis -2 (trailing feature axis stays)
-        out = jnp.full(x.shape[:-2] + (qp, x.shape[-1]), fill, x.dtype)
-        return out.at[..., :Q, :].set(x)
+    def win_major(x):  # [G, W, Q] -> [G, Qp, W] i32
+        return padq(jnp.transpose(x.astype(jnp.int32), (0, 2, 1)))
 
     bounds = jnp.stack(
         [pos_hi.astype(ft), pos_lo1.astype(ft), pos_lo2.astype(ft)],
         axis=-1,
     )
+    qv = jnp.pad(q_vec.astype(ft), ((0, 0), (0, 0), (0, qp - Q), (0, 0)))
     out = pl.pallas_call(
         functools.partial(_kernel, lvl=LVL, npad=NPAD, nw=W),
         grid=(G, qp // tq),
         in_specs=[
             pl.BlockSpec((1, LVL, NPAD), lambda g, q: (g, 0, 0)),
             pl.BlockSpec((1, LVL, NPAD, K), lambda g, q: (g, 0, 0, 0)),
-            pl.BlockSpec((1, W, tq), lambda g, q: (g, 0, q)),
-            pl.BlockSpec((1, W, tq), lambda g, q: (g, 0, q)),
+            pl.BlockSpec((1, tq, W), lambda g, q: (g, q, 0)),
+            pl.BlockSpec((1, tq, W), lambda g, q: (g, q, 0)),
             pl.BlockSpec((1, tq, 3), lambda g, q: (g, q, 0)),
-            pl.BlockSpec((1, tq), lambda g, q: (g, q)),
+            pl.BlockSpec((1, tq, 1), lambda g, q: (g, q, 0)),
             pl.BlockSpec((1, W, tq, K), lambda g, q: (g, 0, q, 0)),
         ],
-        out_specs=pl.BlockSpec((1, W, tq), lambda g, q: (g, 0, q)),
-        out_shape=jax.ShapeDtypeStruct((G, W, qp), ft),
+        out_specs=pl.BlockSpec((1, tq, W), lambda g, q: (g, q, 0)),
+        out_shape=jax.ShapeDtypeStruct((G, qp, W), ft),
         interpret=interpret,
     )(
         pos.astype(ft),
         cum.astype(ft),
-        padq(r_lo.astype(jnp.int32)),
-        padq(r_hi.astype(jnp.int32)),
-        padq_t(bounds),
-        padq(lo1_right.astype(jnp.int32)),
-        padq_t(q_vec.astype(ft)),
+        win_major(r_lo),
+        win_major(r_hi),
+        padq(bounds),
+        _col(padq(lo1_right.astype(jnp.int32))),
+        qv,
     )
-    return out[:, :, :Q]
+    return jnp.transpose(out[:, :Q], (0, 2, 1))

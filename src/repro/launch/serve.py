@@ -39,7 +39,48 @@ import time
 
 import numpy as np
 
-__all__ = ["serve_tnkde", "serve_lm", "main"]
+__all__ = ["tnkde_world", "serve_tnkde", "serve_lm", "main"]
+
+
+def tnkde_world(
+    *,
+    dataset: str = "berkeley",
+    scale: float = 0.02,
+    g: float = 50.0,
+    b_s: float = 1000.0,
+    window_frac: float = 0.25,
+    engine: str = "auto",
+    n_requests: int = 10,
+    stream_every: int = 4,
+    max_windows: int = 3,
+    seed: int = 0,
+):
+    """The served deployment: dataset, base index events, live stream,
+    profile and request mix — what :func:`serve_tnkde` builds its server
+    from (and what ``chip_smoke.py`` drives on the chip).
+
+    Holds back the last 10% of events (by time) as the live stream; the
+    DRFS profile's temporal bandwidth is ``window_frac`` of the span.
+    Returns (net, base, stream, profile, workload, meta).
+    """
+    from repro.core.events import Events
+    from repro.data.spatial import make_dataset
+    from repro.serve import ProfileConfig, make_request_mix
+
+    net, ev, meta = make_dataset(dataset, scale=scale, seed=seed)
+    order = np.argsort(ev.time, kind="stable")
+    cut = int(ev.n * 0.9)
+    base = Events(ev.edge_id[order[:cut]], ev.pos[order[:cut]], ev.time[order[:cut]])
+    stream = Events(ev.edge_id[order[cut:]], ev.pos[order[cut:]], ev.time[order[cut:]])
+    t0, t1 = float(ev.time.min()), float(ev.time.max())
+    b_t = window_frac * (t1 - t0)
+    prof = ProfileConfig(g=g, b_s=b_s, b_t=b_t, drfs_depth=8, engine=engine)
+    workload = make_request_mix(
+        stream, t0 + b_t, t1 - b_t,
+        n_requests=n_requests, stream_every=stream_every,
+        max_windows=max_windows, seed=seed + 7,
+    )
+    return net, base, stream, prof, workload, meta
 
 
 def serve_tnkde(
@@ -91,31 +132,18 @@ def serve_tnkde(
     (seconds; completion − scheduled arrival under the server).
     """
     from repro.core import TNKDE
-    from repro.core.events import Events
-    from repro.data.spatial import make_dataset
     from repro.serve import (
-        ProfileConfig,
         ReplicaRouter,
         TNKDEServer,
-        make_request_mix,
         run_open_loop,
         run_sequential,
         run_server,
     )
 
-    net, ev, meta = make_dataset(dataset, scale=scale, seed=seed)
-    # hold back 10% of events (by time) as the live stream
-    order = np.argsort(ev.time, kind="stable")
-    cut = int(ev.n * 0.9)
-    base = Events(ev.edge_id[order[:cut]], ev.pos[order[:cut]], ev.time[order[:cut]])
-    stream = Events(ev.edge_id[order[cut:]], ev.pos[order[cut:]], ev.time[order[cut:]])
-    t0, t1 = float(ev.time.min()), float(ev.time.max())
-    b_t = window_frac * (t1 - t0)
-    prof = ProfileConfig(g=g, b_s=b_s, b_t=b_t, drfs_depth=8)
-    workload = make_request_mix(
-        stream, t0 + b_t, t1 - b_t,
+    net, base, _, prof, workload, meta = tnkde_world(
+        dataset=dataset, scale=scale, g=g, b_s=b_s, window_frac=window_frac,
         n_requests=n_requests, stream_every=stream_every,
-        max_windows=max_windows, seed=seed + 7,
+        max_windows=max_windows, seed=seed,
     )
 
     t_build = time.perf_counter()
@@ -341,6 +369,9 @@ def main(argv=None):
                          "are shed with a retryable queue_full error")
     ap.add_argument("--arch", default="qwen2.5-3b")
     args = ap.parse_args(argv)
+    from repro.compat import enable_compile_cache
+
+    enable_compile_cache()
     if args.workload == "tnkde":
         serve_tnkde(
             n_requests=args.requests, dataset=args.dataset, scale=args.scale,

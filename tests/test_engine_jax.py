@@ -206,3 +206,56 @@ def test_jax_engine_repeated_queries_consistent(world):
     a = m.query(TS5[:2])
     b = m.query(TS5[:2])
     np.testing.assert_array_equal(a, b)
+
+
+def test_time_keys_order_exactly():
+    """Time keys compare exactly as float64, where f32 ties (it steps by
+    0.5 s at 7.8e6 s) — the comparison the device runs on window bounds."""
+    import jax.numpy as jnp
+
+    from repro.core.jax_engine import _key_le, _key_lt, time_key
+
+    rng = np.random.default_rng(0)
+    base = rng.uniform(0.0, 7.8e6, 200)
+    t = np.concatenate([
+        base, base + 1e-3, base - 0.25, np.nextafter(base, np.inf),
+        [-5.0, -0.0, 0.0, 1.7e9, 1.7e9 + 1e-6, np.inf, -np.inf],
+    ])
+    k = jnp.asarray(time_key(t))
+    a, b = k[:, :, None], k[:, None, :]
+    np.testing.assert_array_equal(np.asarray(_key_lt(a, b)), t[:, None] < t[None, :])
+    np.testing.assert_array_equal(np.asarray(_key_le(a, b)), t[:, None] <= t[None, :])
+    t32 = t.astype(np.float32)
+    assert (t32[:, None] < t32[None, :]).sum() < (t[:, None] < t[None, :]).sum()
+
+
+@pytest.mark.parametrize("solution", ["rfs", "drfs"])
+def test_f32_device_path_keeps_window_bounds_exact(monkeypatch, solution):
+    """The accelerator's numeric path (f32 tables and heatmaps, int32 time
+    keys), steered onto the CPU: events 0.1 s either side of a window bound
+    at 5.6e6 s, where f32 times would round onto the bound, stay on their
+    side, and the heatmap matches the f64 NumPy oracle to f32 accuracy."""
+    import repro.compat as compat
+    from repro.core.events import Events
+
+    net = make_network(30, 50, seed=3)
+    ev = make_events(net, 600, seed=4, span_days=90)
+    t, b_t = 6_000_000.0, 432_000.0  # window bounds exactly representable
+    plant = np.array([-0.1, 0.1] * 4)
+    extra = np.concatenate([t - b_t + plant, t + b_t + plant])
+    e0 = int(np.argmax(net.edge_len))
+    ev = Events(
+        np.concatenate([ev.edge_id, np.full(len(extra), e0)]),
+        np.concatenate([ev.pos, np.full(len(extra), 0.5 * net.edge_len[e0])]),
+        np.concatenate([ev.time, extra]),
+    )
+    kw = dict(g=40.0, b_s=600.0, b_t=b_t, solution=solution,
+              temporal_kernel="uniform", drfs_depth=4)
+    ts = [t, t + 86400.0]
+    ref = TNKDE(net, ev, engine="numpy", **kw).query(ts)
+    monkeypatch.setattr(compat, "device_x64", lambda: False)
+    m = TNKDE(net, ev, engine="jax", **kw)
+    got = m.query(ts)
+    assert np.isfinite(got).all()
+    scale = np.abs(ref).max()
+    np.testing.assert_allclose(got, ref, rtol=0, atol=2e-5 * scale)

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 
 pytestmark = pytest.mark.slow  # interpret-mode sweeps; scheduled CI job
 
+from repro.compat import x64
 from repro.kernels import ops, ref
 
 
@@ -109,12 +110,13 @@ def test_dyn_leaf_query_matches_ref(nleaf, K, Q, W, dtype):
     G = 3
     R = (nleaf + 1) * 2
     tab = np.cumsum(rng.normal(size=(G, R, W * 2 * K)), axis=1)  # prefix-like
+    tab = np.swapaxes(tab, 1, 2)  # feature-major [G, W·2K, R]
     leaf_lo = rng.integers(0, nleaf + 1, (G, Q))
     leaf_hi = np.maximum(rng.integers(0, nleaf + 1, (G, Q)), leaf_lo)
     side = rng.integers(0, 2, (G, Q))
     qv_l = rng.normal(size=(G, W, Q, K))
     qv_r = rng.normal(size=(G, W, Q, K))
-    with jax.experimental.enable_x64(dtype == jnp.float64):
+    with x64(dtype == jnp.float64):
         args = [jnp.asarray(x, dtype) if np.issubdtype(np.asarray(x).dtype, np.floating)
                 else jnp.asarray(x) for x in (tab, leaf_lo, leaf_hi, side, qv_l, qv_r)]
         got = np.asarray(ops.dyn_leaf_query(*args, tq=32))
@@ -130,13 +132,13 @@ def test_dyn_node_walk_matches_ref(hq, ks, Q, W, dtype):
     rng = np.random.default_rng(hq * 100 + Q)
     G = 3
     R2 = ((1 << (hq + 1)) - 1) * 2
-    nv = rng.normal(size=(G, R2, W * 2 * ks))
+    nv = rng.normal(size=(G, W * 2 * ks, R2))  # feature-major
     nleaf = 1 << hq
     r_lo = rng.integers(0, nleaf + 1, (G, Q))
     r_hi = np.maximum(rng.integers(0, nleaf + 1, (G, Q)), r_lo)
     side = rng.integers(0, 2, (G, Q))
     qs = rng.normal(size=(G, Q, ks))
-    with jax.experimental.enable_x64(dtype == jnp.float64):
+    with x64(dtype == jnp.float64):
         args = [jnp.asarray(x, dtype) if np.issubdtype(np.asarray(x).dtype, np.floating)
                 else jnp.asarray(x) for x in (nv, r_lo, r_hi, side, qs)]
         got = np.asarray(ops.dyn_node_walk(*args, hq=hq, tq=32))
@@ -174,12 +176,12 @@ def test_fused_walk_matches_ref(layout, Q, W, ks, dtype):
         rank_hi = 1 << n
     rng = np.random.default_rng(R * 100 + Q)
     G = 3
-    nv = rng.normal(size=(G, R * 2, W * 2 * ks))
+    nv = rng.normal(size=(G, W * 2 * ks, R * 2))  # feature-major
     r_lo = rng.integers(0, rank_hi + 1, (G, Q))
     r_hi = np.maximum(rng.integers(0, rank_hi + 1, (G, Q)), r_lo)
     side = rng.integers(0, 2, (G, Q))
     qs = rng.normal(size=(G, Q, ks))
-    with jax.experimental.enable_x64(dtype == jnp.float64):
+    with x64(dtype == jnp.float64):
         args = [jnp.asarray(x, dtype) if np.issubdtype(np.asarray(x).dtype, np.floating)
                 else jnp.asarray(x) for x in (nv, r_lo, r_hi, side, qs)]
         got = np.asarray(ops.fused_walk(*args, offs=offs, tq=32))
@@ -197,14 +199,14 @@ def test_fused_leaf_matches_ref(nleaf, ks, kt, Q, W, dtype):
     G = 3
     R = (nleaf + 1) * 2
     K = ks * kt
-    tab = np.cumsum(rng.normal(size=(G, R, W * 2 * K)), axis=1)
+    tab = np.swapaxes(np.cumsum(rng.normal(size=(G, R, W * 2 * K)), axis=1), 1, 2)
     leaf_lo = rng.integers(0, nleaf + 1, (G, Q))
     leaf_hi = np.maximum(rng.integers(0, nleaf + 1, (G, Q)), leaf_lo)
     side = rng.integers(0, 2, (G, Q))
     qs = rng.normal(size=(G, Q, ks))
     qtl = rng.normal(size=(W, kt))
     qtr = rng.normal(size=(W, kt))
-    with jax.experimental.enable_x64(dtype == jnp.float64):
+    with x64(dtype == jnp.float64):
         args = [jnp.asarray(x, dtype) if np.issubdtype(np.asarray(x).dtype, np.floating)
                 else jnp.asarray(x) for x in (tab, leaf_lo, leaf_hi, side, qs, qtl, qtr)]
         got = np.asarray(ops.fused_leaf(*args, tq=32))
