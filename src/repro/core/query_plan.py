@@ -225,7 +225,7 @@ def build_host_plan(
 ) -> HostPlan:
     """One planning walk of ``model.edge_geometries()`` → a cached HostPlan.
 
-    ``model`` is the TNKDE instance (the walk charges its ``sp_seconds``).
+    ``model`` is the TNKDE instance whose ``edge_geometries`` it walks.
     Lixel-Sharing classification happens here — dominated candidates are
     deferred into ``plan.dominated`` exactly as the inline path did.
     """

@@ -78,6 +78,7 @@ from .rfs import RangeForest
 from .shortest_path import adjacency_csr, bounded_dijkstra
 from .sps import sps_eval_edge
 from . import wal as _wal
+from ..spans import span
 
 __all__ = ["TNKDE", "PendingQuery", "QueryStats"]
 
@@ -86,7 +87,6 @@ __all__ = ["TNKDE", "PendingQuery", "QueryStats"]
 class QueryStats:
     build_seconds: float = 0.0
     query_seconds: float = 0.0
-    sp_seconds: float = 0.0
     n_atoms: int = 0
     n_pairs_dominated: int = 0
     n_pairs_out: int = 0
@@ -763,9 +763,7 @@ class TNKDE:
             verts = np.unique(
                 np.concatenate([net.edge_src[blk], net.edge_dst[blk]])
             )
-            t_sp = _time.perf_counter()
             rows = bounded_dijkstra(net, verts, radius, adj=self._adj)
-            self.stats.sp_seconds += _time.perf_counter() - t_sp
             vmap = {int(v): i for i, v in enumerate(verts)}
             for a in blk:
                 ra = rows[vmap[int(net.edge_src[a])]]
@@ -796,7 +794,8 @@ class TNKDE:
                 # flush) stays within device memory
                 else min(self.atom_flush, 200_000)
             )
-            plan = build_host_plan(self, key, flush_cap=cap, ls=self.ls)
+            with span("tnkde.plan"):
+                plan = build_host_plan(self, key, flush_cap=cap, ls=self.ls)
             self._plan_cache.put(key, plan)
         return plan
 
@@ -849,14 +848,16 @@ class TNKDE:
         if use_jax:
             # all W windows ride one device pass per block; the heatmap stays
             # device-resident (and the flush merely *enqueued*) until result()
-            wb = self._fe.window_batch(ctx, ts)
-            heat = self._fe.new_heatmap(L, W)
-            heat = self._fe.flush_plan(
-                heat, plan, wb, tuple(ts),
-                h0=self.drfs_h0,
-                exact_leaf=self.drfs_exact_leaf,
-                snapshot=snap,
-            )
+            with span("tnkde.window_batch"):
+                wb = self._fe.window_batch(ctx, ts)
+            with span("tnkde.enqueue"):
+                heat = self._fe.new_heatmap(L, W)
+                heat = self._fe.flush_plan(
+                    heat, plan, wb, tuple(ts),
+                    h0=self.drfs_h0,
+                    exact_leaf=self.drfs_exact_leaf,
+                    snapshot=snap,
+                )
         else:
             for atoms in plan.blocks:
                 for w, t in enumerate(ts):
@@ -966,12 +967,14 @@ class PendingQuery:
         if self._heat is not None:
             # the blocking device->host transfer (everything enqueued by
             # dispatch completes before the bytes land)
-            self._F += model._fe.to_numpy(self._heat)
+            with span("tnkde.transfer"):
+                self._F += model._fe.to_numpy(self._heat)
             self._heat = None
         # ---- Lixel Sharing: dominated edges, batched across the network ----
         if self._plan.dominated:
-            dominated_sweep(self._F, self._idx, model.ctx, self._plan.dominated,
-                            self._ts)
+            with span("tnkde.ls_sweep"):
+                dominated_sweep(self._F, self._idx, model.ctx,
+                                self._plan.dominated, self._ts)
         model._consume_counters(self._use_jax)
         model.stats.query_seconds += _time.perf_counter() - t0
         if model.index is not None and hasattr(model.index, "index_bytes"):

@@ -50,6 +50,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..spans import span
 from . import errors as _errors
 from .cache import ResultCache
 from .errors import QueueFull, ServeError
@@ -296,13 +297,19 @@ class SlotScheduler:
 
 class InFlightFlush:
     """One dispatched, unretired flush: the slots it owns, the pinned
-    snapshot, the cache rows it reused and the PendingQuery it blocks on."""
+    snapshot, the cache rows it reused and the PendingQuery it blocks on.
 
-    __slots__ = ("slots", "profile", "epoch", "snapshot", "misses", "rowmap",
-                 "deferred", "pending", "n_eval", "t_dispatch", "atoms",
-                 "error")
+    ``ahead`` is the engine flush in flight ahead of it at dispatch, held
+    until this one retires: its device pass starts at the latest when the
+    one ahead has landed (``t_ready``), which gives the requests'
+    ``inflight_wait_seconds``."""
 
-    def __init__(self, slots, profile, epoch, snapshot):
+    __slots__ = ("flush_id", "slots", "profile", "epoch", "snapshot", "misses",
+                 "rowmap", "deferred", "pending", "n_eval", "t_dispatch",
+                 "t_ready", "ahead", "atoms", "error")
+
+    def __init__(self, flush_id, slots, profile, epoch, snapshot):
+        self.flush_id = flush_id
         self.slots = slots
         self.profile = profile
         self.epoch = epoch
@@ -314,12 +321,22 @@ class InFlightFlush:
         self.pending = None  # PendingQuery; None = pure cache hit or fault
         self.n_eval = 0  # padded centers sent to the engine
         self.t_dispatch = 0.0
+        self.t_ready: Optional[float] = None  # engine result on the host
+        self.ahead: Optional["InFlightFlush"] = None
         self.atoms = 0
         self.error: Optional[ServeError] = None  # dispatch-stage fault
 
     @property
     def requests(self) -> List[Request]:
         return [s.req for s in self.slots]
+
+    def inflight_wait(self) -> float:
+        """Dispatch to the estimated start of this flush's device pass:
+        ``max(t_dispatch, t_ready of the flush ahead)`` less ``t_dispatch``;
+        0 into an empty pipeline."""
+        if self.ahead is None or self.ahead.t_ready is None:
+            return 0.0
+        return max(self.ahead.t_ready - self.t_dispatch, 0.0)
 
 
 class ContinuousCore:
@@ -349,6 +366,7 @@ class ContinuousCore:
             raise ValueError("flush_cap must be >= 1")
         self.slo_margin = None if slo_margin is None else float(slo_margin)
         self._inflight: List[InFlightFlush] = []
+        self._n_dispatched = 0  # flush ids, in dispatch order
         # flush-time EWMA (dispatch -> retire wall) driving the SLO shed
         self.flush_ewma_s: Optional[float] = None
 
@@ -413,49 +431,54 @@ class ContinuousCore:
         if not slots:
             return None
         req0 = slots[0].req
-        fl = InFlightFlush(slots, req0.profile, req0.epoch, snapshot)
-        fl.t_dispatch = time.perf_counter()
-        try:
-            # distinct centers in arrival order; probe the epoch-keyed cache
-            seen: "OrderedDict[float, None]" = OrderedDict()
-            for s in slots:
-                for t in s.req.ts:
-                    seen.setdefault(float(t))
-            # rows an earlier in-flight flush at this key is already
-            # computing: defer instead of duplicating the engine work
-            computing = {c for f2 in self._inflight
-                         if (f2.profile, f2.epoch) == (fl.profile, fl.epoch)
-                         and f2.error is None
-                         for c in f2.misses}
-            for c in seen:
-                row = server.cache.get(ResultCache.key(fl.profile, fl.epoch, c))
-                if row is not None:
-                    fl.rowmap[c] = row
-                elif c in computing:
-                    fl.deferred.append(c)
-                else:
-                    fl.misses.append(c)
-            server.stats.n_flushes += 1
-            server.stats.occupancy_sum += len(slots) / self.scheduler.n_slots
-            if not fl.misses:
-                return fl  # cache hits + deferred rows: no engine pass
-            model = server.models[fl.profile]
-            wc = window_class(len(fl.misses), server.window_cap)
-            eval_ts = fl.misses + [fl.misses[0]] * (wc - len(fl.misses))
-            fl.n_eval = len(eval_ts)
-            atoms0 = model.stats.n_atoms
+        fl = InFlightFlush(self._n_dispatched, slots, req0.profile, req0.epoch,
+                           snapshot)
+        self._n_dispatched += 1
+        fl.ahead = next((f for f in reversed(self._inflight)
+                         if f.pending is not None), None)
+        with span("serve.dispatch", flush=fl.flush_id):
+            fl.t_dispatch = time.perf_counter()
             try:
-                fl.pending = model.dispatch(eval_ts, at=fl.snapshot)
-            except Exception as e:
-                # engine/injector fault at dispatch: the §8 envelope resolves
-                # it at retire time (retry-once if transient)
-                fl.error = server._fault_error(e)
-            fl.atoms = model.stats.n_atoms - atoms0
-        except Exception as e:  # defense in depth: a bug in the probe/pack
-            # path itself must not lose the group's slots
-            fl.error = ServeError(
-                code=_errors.INTERNAL, message=f"{type(e).__name__}: {e}"
-            )
+                # distinct centers in arrival order; probe the epoch-keyed cache
+                seen: "OrderedDict[float, None]" = OrderedDict()
+                for s in slots:
+                    for t in s.req.ts:
+                        seen.setdefault(float(t))
+                # rows an earlier in-flight flush at this key is already
+                # computing: defer instead of duplicating the engine work
+                computing = {c for f2 in self._inflight
+                             if (f2.profile, f2.epoch) == (fl.profile, fl.epoch)
+                             and f2.error is None
+                             for c in f2.misses}
+                for c in seen:
+                    row = server.cache.get(ResultCache.key(fl.profile, fl.epoch, c))
+                    if row is not None:
+                        fl.rowmap[c] = row
+                    elif c in computing:
+                        fl.deferred.append(c)
+                    else:
+                        fl.misses.append(c)
+                server.stats.n_flushes += 1
+                server.stats.occupancy_sum += len(slots) / self.scheduler.n_slots
+                if not fl.misses:
+                    return fl  # cache hits + deferred rows: no engine pass
+                model = server.models[fl.profile]
+                wc = window_class(len(fl.misses), server.window_cap)
+                eval_ts = fl.misses + [fl.misses[0]] * (wc - len(fl.misses))
+                fl.n_eval = len(eval_ts)
+                atoms0 = model.stats.n_atoms
+                try:
+                    fl.pending = model.dispatch(eval_ts, at=fl.snapshot)
+                except Exception as e:
+                    # engine/injector fault at dispatch: the §8 envelope resolves
+                    # it at retire time (retry-once if transient)
+                    fl.error = server._fault_error(e)
+                fl.atoms = model.stats.n_atoms - atoms0
+            except Exception as e:  # defense in depth: a bug in the probe/pack
+                # path itself must not lose the group's slots
+                fl.error = ServeError(
+                    code=_errors.INTERNAL, message=f"{type(e).__name__}: {e}"
+                )
         return fl
 
     # -------------------------------------------------------------- retire
@@ -468,6 +491,9 @@ class ContinuousCore:
                 F = fl.pending.result()  # blocks on the device
             except Exception as e:
                 err = server._fault_error(e)
+            fl.t_ready = time.perf_counter()
+        inflight_wait = fl.inflight_wait()
+        fl.ahead = None  # retired FIFO: the one ahead is done with
         if err is not None and err.retryable and fl.misses:
             # transient fault: ONE synchronous retry, like _query_guarded
             server.stats.n_retries += 1
@@ -516,48 +542,53 @@ class ContinuousCore:
                     server.stats.n_rows_computed += len(missing)
                 except Exception as e:
                     err = server._fault_error(e)
-        out: List = []
-        if err is not None:
-            server._note_flush_failed(fl.profile)
-            for req in fl.requests:
-                stats = server._mk_stats(
-                    epoch=fl.epoch, queue_seconds=fl.t_dispatch - req.arrival,
-                    n_ts=len(req.ts), batch_size=len(fl.slots),
-                )
-                out.append(server._mk_error_response(req, stats, err))
-        else:
-            if F is not None:
-                server._fault_streak[fl.profile] = 0
-                for i, c in enumerate(fl.misses):
-                    # copy: a view would pin the whole padded [W, L] batch
-                    row = F[i].copy()
-                    fl.rowmap[c] = row
-                    server.cache.put(
-                        ResultCache.key(fl.profile, fl.epoch, c), row
+        with span("serve.assemble", flush=fl.flush_id):
+            out: List = []
+            if err is not None:
+                server._note_flush_failed(fl.profile)
+                for req in fl.requests:
+                    stats = server._mk_stats(
+                        epoch=fl.epoch, queue_seconds=fl.t_dispatch - req.arrival,
+                        n_ts=len(req.ts), batch_size=len(fl.slots),
+                        inflight_wait_seconds=inflight_wait,
+                        flush_id=fl.flush_id,
                     )
-            L = server.models[fl.profile].n_lixels
-            miss_set = set(fl.misses)
-            for s in fl.slots:
-                req = s.req
-                heat = (np.stack([fl.rowmap[float(t)] for t in req.ts])
-                        if req.ts else np.zeros((0, L)))
-                if req.lixels is not None:
-                    heat = heat[:, req.lixels]
-                hits = sum(1 for t in req.ts if float(t) not in miss_set)
-                stats = server._mk_stats(
-                    epoch=fl.epoch,
-                    queue_seconds=fl.t_dispatch - req.arrival,
-                    service_seconds=service,
-                    batch_size=len(fl.slots),
-                    windows_evaluated=fl.n_eval,
-                    cache_hits=hits,
-                    n_ts=len(req.ts),
-                    atoms=fl.atoms,
-                )
-                out.append(server._mk_ok_response(req, heat, stats))
-            server.stats.n_windows_evaluated += fl.n_eval
-            server.stats.n_rows_computed += len(fl.misses)
-            server.stats.service_seconds += service
+                    out.append(server._mk_error_response(req, stats, err))
+            else:
+                if F is not None:
+                    server._fault_streak[fl.profile] = 0
+                    for i, c in enumerate(fl.misses):
+                        # copy: a view would pin the whole padded [W, L] batch
+                        row = F[i].copy()
+                        fl.rowmap[c] = row
+                        server.cache.put(
+                            ResultCache.key(fl.profile, fl.epoch, c), row
+                        )
+                L = server.models[fl.profile].n_lixels
+                miss_set = set(fl.misses)
+                for s in fl.slots:
+                    req = s.req
+                    heat = (np.stack([fl.rowmap[float(t)] for t in req.ts])
+                            if req.ts else np.zeros((0, L)))
+                    if req.lixels is not None:
+                        heat = heat[:, req.lixels]
+                    hits = sum(1 for t in req.ts if float(t) not in miss_set)
+                    stats = server._mk_stats(
+                        epoch=fl.epoch,
+                        queue_seconds=fl.t_dispatch - req.arrival,
+                        service_seconds=service,
+                        batch_size=len(fl.slots),
+                        windows_evaluated=fl.n_eval,
+                        cache_hits=hits,
+                        n_ts=len(req.ts),
+                        atoms=fl.atoms,
+                        inflight_wait_seconds=inflight_wait,
+                        flush_id=fl.flush_id,
+                    )
+                    out.append(server._mk_ok_response(req, heat, stats))
+                server.stats.n_windows_evaluated += fl.n_eval
+                server.stats.n_rows_computed += len(fl.misses)
+                server.stats.service_seconds += service
         self.scheduler.retire(fl.slots)
         server.stats.n_batches += 1
         return out
@@ -572,6 +603,7 @@ class ContinuousCore:
             stats = server._mk_stats(
                 epoch=fl.epoch, queue_seconds=fl.t_dispatch - req.arrival,
                 n_ts=len(req.ts), batch_size=len(fl.slots),
+                flush_id=fl.flush_id,
             )
             out.append(server._mk_error_response(req, stats, err))
         self.scheduler.retire(fl.slots)
@@ -606,10 +638,11 @@ class ContinuousCore:
             if not self._inflight:
                 break
             fl = self._inflight.pop(0)
-            try:
-                out.extend(self._retire(fl))
-            except Exception as e:  # defense in depth (see _fail_flush)
-                out.extend(self._fail_flush(fl, e))
+            with span("serve.retire", flush=fl.flush_id):
+                try:
+                    out.extend(self._retire(fl))
+                except Exception as e:  # defense in depth (see _fail_flush)
+                    out.extend(self._fail_flush(fl, e))
             if not force:
                 break
         server.stats.slots_occupied = self.scheduler.slots_occupied

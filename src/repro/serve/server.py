@@ -97,6 +97,10 @@ class RequestStats:
     cache_hits: int  # this request's centers served from cache
     cache_misses: int
     atoms: int  # engine atoms the batch flushed (shared roll-up)
+    # continuous core only: dispatch -> estimated device start, the wait
+    # behind the engine flush in flight ahead (0 into an empty pipeline)
+    inflight_wait_seconds: float = 0.0
+    flush_id: Optional[int] = None  # the ``flush=`` of the serve spans
 
 
 @dataclasses.dataclass
@@ -485,6 +489,8 @@ class TNKDEServer:
         windows_evaluated: int = 0,
         cache_hits: int = 0,
         atoms: int = 0,
+        inflight_wait_seconds: float = 0.0,
+        flush_id: Optional[int] = None,
     ) -> RequestStats:
         return RequestStats(
             epoch=epoch,
@@ -495,6 +501,8 @@ class TNKDEServer:
             cache_hits=cache_hits,
             cache_misses=n_ts - cache_hits,
             atoms=atoms,
+            inflight_wait_seconds=inflight_wait_seconds,
+            flush_id=flush_id,
         )
 
     def _mk_ok_response(self, req: Request, heat, stats: RequestStats) -> Response:
