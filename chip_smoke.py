@@ -415,7 +415,7 @@ def _device_arrays(fe):
             for s in x.__slots__:
                 walk(getattr(x, s, None))
 
-    for name in ("_pf", "_node_starts", "_nbl", "_tab_cache", "_pack_cache",
+    for name in ("_pf", "_nbl", "_tab_cache", "_pack_cache",
                  "_sealed_packs", "_pend_packs", "_wb_cache"):
         walk(getattr(fe, name, None))
     return found

@@ -105,32 +105,32 @@ class ShardedPackedForest:
     """Stacked per-shard slabs of the packed position-major layout.
 
     Every array carries a leading shard axis; per-shard contents are the
-    `jax_engine.PackedForest` tables of that shard's edges, rebased to the
-    slab and addressed by shard-LOCAL edge slots (``edge_slot`` maps global
-    edge ids; atoms are routed with local ids, so non-owned edges simply do
-    not exist on a shard). Slabs are padded to the max across shards —
-    shard_map requires uniform shapes — with +inf position/time pads and
-    node-start slot 0 for the padding nodes (their folded values are never
-    gathered by the walk).
+    `jax_engine.PackedForest` leaf tables of that shard's edges, laid out by
+    descending ``n_pad`` inside the shard (as `rfs.build_packed_host_tables`
+    does for the whole forest) and addressed by shard-LOCAL edge slots
+    (``edge_slot`` maps global edge ids; atoms are routed with local ids, so
+    non-owned edges simply do not exist on a shard). Slabs are padded to the
+    max across shards — shard_map requires uniform shapes — with +inf
+    position/time pads and zero Φ; every level's width is the max of the
+    shards' node counts (``level_nodes``), and since 2·NL_ℓ ≤ NL_{ℓ−1}
+    holds for each shard it holds for the maxima, so the dense build's
+    pairwise sums stay in bounds. Pad columns are never addressed by
+    ``node_base_lvl``.
     """
 
     pm_pos: np.ndarray  # [S, Pmax]
     pos_base: np.ndarray  # [S, El]
-    pm_time: np.ndarray  # [S, Tmax]
-    pm_cum: np.ndarray  # [S, Tmax, 4, K]
-    edge_base: np.ndarray  # [S, El]
+    pm_time: np.ndarray  # [S, Pmax] leaf times, position order
+    pm_phi: np.ndarray  # [S, Pmax, 4, K] leaf raw Φ rows, same order
     n_pad: np.ndarray  # [S, El]
-    n_lev: np.ndarray  # [S, El]
     node_base_lvl: np.ndarray  # [S, Lmax, El] walk level → local node base
-    node_starts: Tuple[np.ndarray, ...]  # per level: [S, NLmax_lev] run offsets
     shard_of_edge: np.ndarray  # [E]
     edge_slot: np.ndarray  # [E] global edge → local slot on its shard
     events_per_shard: np.ndarray  # [S]
     max_levels: int
     search_steps: int
-    steps_per_level: tuple
+    level_nodes: tuple  # padded per-level node widths (uniform)
     n_shards: int
-    n_nodes: int  # padded per-shard node count (uniform)
     # per-shard byte accounting lives on the engines (_ShardedBase.
     # bytes_per_shard over the actual device arrays) — one accounting path
 
@@ -139,87 +139,69 @@ def build_sharded_packed(rf, n_shards: int) -> ShardedPackedForest:
     """Slab a built RangeForest's packed tables into per-shard rebased slabs.
 
     Builds the position-major host tables once (`rfs.build_packed_host_tables`
-    — the identical transpose the single-host engine uploads) and relocates
-    each edge's blocks into its shard's slab; node ids are re-assigned
-    level-major within the shard with per-level blocks padded to the max
-    across shards, so `packed_node_tables`'s concatenated nodeval layout and
-    ``node_base_lvl`` agree on every shard.
+    — the identical leaves the single-host engine uploads) and relocates
+    each edge's leaf block into its shard's slab, owned edges by descending
+    ``n_pad``; node ids are re-assigned level-major within the shard with
+    per-level blocks padded to the max across shards, so
+    `packed_node_tables`'s concatenated nodeval layout and ``node_base_lvl``
+    agree on every shard.
     """
     from .rfs import build_packed_host_tables
 
     host = build_packed_host_tables(rf)
-    E = rf.net.n_edges
     counts = np.diff(rf.ee.ptr)
     shard_of = assign_edges(counts, n_shards)
     S = max(int(n_shards), 1)
     owned, El, edge_slot = _owned_lists(shard_of, S)
     n_pad_g = np.asarray(host["n_pad"], np.int64)
-    n_lev_g = np.asarray(host["n_lev"], np.int64)
+    n_lev_g = np.asarray(rf.n_levels, np.int64)
     K = rf.ctx.K
     Lmax = max(rf.max_levels, 1)
-    Pmax = max(max((int(n_pad_g[o].sum()) for o in owned), default=0), 1)
-    Tmax = max(max((int((n_pad_g[o] * n_lev_g[o]).sum()) for o in owned), default=0), 1)
     nl_cnt = np.zeros((S, Lmax), np.int64)
     for s, o in enumerate(owned):
         for lev in range(Lmax):
-            sel = o[n_lev_g[o] > lev]
-            nl_cnt[s, lev] = int((n_pad_g[sel] >> lev).sum())
+            nl_cnt[s, lev] = int(n_pad_g[o[n_lev_g[o] > lev]].sum()) >> lev
     NL = np.maximum(nl_cnt.max(axis=0, initial=0), 1)  # [Lmax] padded widths
     lev_base = np.concatenate([[0], np.cumsum(NL)])
+    Pmax = int(NL[0])
 
     pm_pos = np.full((S, Pmax), np.inf)
-    pm_time = np.full((S, Tmax), np.inf)
-    pm_cum = np.zeros((S, Tmax, N_COMBOS, K))
+    pm_time = np.full((S, Pmax), np.inf)
+    pm_phi = np.zeros((S, Pmax, N_COMBOS, K))
     pos_base = np.zeros((S, El), np.int64)
-    edge_base = np.zeros((S, El), np.int64)
     n_pad = np.zeros((S, El), np.int64)
-    n_lev = np.zeros((S, El), np.int64)
     node_base_lvl = np.zeros((S, Lmax, El), np.int32)
-    node_starts = [np.zeros((S, int(NL[lev])), np.int32) for lev in range(Lmax)]
     for s, o in enumerate(owned):
-        p_off = t_off = 0
-        n_off = np.zeros(Lmax, np.int64)
-        for j, e in enumerate(o):
-            npd, nlv = int(n_pad_g[e]), int(n_lev_g[e])
-            n_pad[s, j] = npd
-            n_lev[s, j] = nlv
+        n_pad[s, : len(o)] = n_pad_g[o]
+        p_off = 0
+        for j in np.argsort(-n_pad_g[o], kind="stable"):
+            e = o[j]
+            npd = int(n_pad_g[e])
             if npd == 0:
                 continue
-            gp, gt = int(host["pos_base"][e]), int(host["edge_base"][e])
+            gp = int(host["pos_base"][e])
             pm_pos[s, p_off : p_off + npd] = host["pm_pos"][gp : gp + npd]
+            pm_time[s, p_off : p_off + npd] = host["pm_time"][gp : gp + npd]
+            pm_phi[s, p_off : p_off + npd] = host["pm_phi"][gp : gp + npd]
             pos_base[s, j] = p_off
+            for lev in range(int(n_lev_g[e])):
+                node_base_lvl[s, lev, j] = lev_base[lev] + (p_off >> lev)
             p_off += npd
-            blk = npd * nlv
-            pm_time[s, t_off : t_off + blk] = host["pm_time"][gt : gt + blk]
-            pm_cum[s, t_off : t_off + blk] = host["pm_cum"][gt : gt + blk]
-            edge_base[s, j] = t_off
-            for lev in range(nlv):
-                nb = npd >> lev
-                node_base_lvl[s, lev, j] = lev_base[lev] + n_off[lev]
-                node_starts[lev][s, n_off[lev] : n_off[lev] + nb] = (
-                    t_off + lev * npd + np.arange(nb, dtype=np.int64) * (1 << lev)
-                )
-                n_off[lev] += nb
-            t_off += blk
     ev_per_shard = np.bincount(shard_of, weights=counts.astype(np.float64), minlength=S)
     return ShardedPackedForest(
         pm_pos=pm_pos,
         pos_base=pos_base,
         pm_time=pm_time,
-        pm_cum=pm_cum,
-        edge_base=edge_base,
+        pm_phi=pm_phi,
         n_pad=n_pad,
-        n_lev=n_lev,
         node_base_lvl=node_base_lvl,
-        node_starts=tuple(node_starts),
         shard_of_edge=shard_of,
         edge_slot=edge_slot,
         events_per_shard=ev_per_shard.astype(np.int64),
         max_levels=Lmax,
         search_steps=max(int(np.ceil(np.log2(max(int(n_pad_g.max(initial=1)), 1) + 1))) + 1, 1),
-        steps_per_level=tuple(host["steps_per_level"]),
+        level_nodes=tuple(int(n) for n in NL),
         n_shards=S,
-        n_nodes=int(lev_base[-1]),
     )
 
 
@@ -282,16 +264,15 @@ def _get_programs(mesh, axes: Tuple[str, ...]):
         return heat + jax.lax.psum(delta, ax)
 
     # ---- static RFS: node tables, root ranks, flush ------------------------
-    @functools.partial(jax.jit, static_argnames=("steps_per_level", "k_t"))
-    def rfs_tables(pf, wb, node_starts, *, steps_per_level, k_t):
-        def body(pf, wb, node_starts):
-            ns = tuple(x[0] for x in node_starts)
+    @functools.partial(jax.jit, static_argnames=("level_nodes", "k_t"))
+    def rfs_tables(pf, wb, *, level_nodes, k_t):
+        def body(pf, wb):
             out = packed_node_tables(
-                _local(pf), wb, ns, steps_per_level=steps_per_level, k_t=k_t
+                _local(pf), wb, level_nodes=level_nodes, k_t=k_t
             )
             return out[None]
 
-        return _smap(body, (spec, rep, spec), spec)(pf, wb, node_starts)
+        return _smap(body, (spec, rep), spec)(pf, wb)
 
     @functools.partial(jax.jit, static_argnames=("search_steps",))
     def rfs_roots(pf, fa, *, search_steps):
@@ -449,29 +430,19 @@ class ShardedForestEngine(_ShardedBase):
                 pm_pos=self._shard_put(self.sf.pm_pos),
                 pos_base=self._shard_put(self.sf.pos_base),
                 pm_time=self._shard_put(_slab_keys(self.sf.pm_time)),
-                pm_cum=self._shard_put(feature_major(self.sf.pm_cum)),
-                edge_base=self._shard_put(self.sf.edge_base),
+                pm_phi=self._shard_put(feature_major(self.sf.pm_phi)),
                 n_pad=self._shard_put(self.sf.n_pad),
-                n_lev=self._shard_put(self.sf.n_lev),
-                # no sharded program reads pf.node_base (the walk takes the
-                # level-major _nbl directly) — reuse that buffer instead of
-                # uploading a second transposed copy the memory metric would
-                # then count
-                node_base=self._nbl,
             )
-            self._node_starts = tuple(self._shard_put(s) for s in self.sf.node_starts)
         self._tab_cache = PlanCache(2)
         self._pack_cache = PlanCache(2)
         self._mesh_key = (tuple(sorted(mesh.shape.items())), self.axes)
 
     @property
     def device_bytes(self) -> int:
-        # _nbl is aliased into self._pf.node_base — listing both would
-        # double-count the one buffer
         return _device_nbytes(
             [
                 self._pf,
-                list(self._node_starts),
+                self._nbl,
                 list(self._tab_cache.values()),
                 list(self._pack_cache.values()),
             ]
@@ -481,7 +452,7 @@ class ShardedForestEngine(_ShardedBase):
         """Sharded q_t-folded node values [S, W·2k_s, 2R], LRU per ts.
 
         Same hoist, same builder (`packed_node_tables`), run per shard over
-        the slab's node runs — all time searches stay at node-count scale.
+        the slab's leaves — one dense fold per shard, no searches.
         """
         key = (ts_key, self._mesh_key)
         hit = self._tab_cache.get(key)
@@ -490,13 +461,12 @@ class ShardedForestEngine(_ShardedBase):
         W = len(ts_key)
         with self._precision():
             tabs = self._progs["rfs_tables"](
-                self._pf, wb, self._node_starts,
-                steps_per_level=self.sf.steps_per_level,
+                self._pf, wb,
+                level_nodes=self.sf.level_nodes,
                 k_t=int(self.rf.ctx.k_t),
             )
-        nn = self.sf.n_nodes * self.n_shards
-        self.counters["rank_searches"] += 3 * W * nn
-        self.counters["moment_gathers"] += 3 * W * nn
+        # every shard folds its padded slab of leaves
+        self.counters["table_leaves"] += W * self.sf.level_nodes[0] * self.n_shards
         self._tab_cache.put(key, tabs)
         return tabs
 
@@ -553,10 +523,10 @@ class ShardedForestEngine(_ShardedBase):
             tabs_s = jax.eval_shape(
                 ft.partial(
                     self._progs["rfs_tables"],
-                    steps_per_level=self.sf.steps_per_level,
+                    level_nodes=self.sf.level_nodes,
                     k_t=int(self.rf.ctx.k_t),
                 ),
-                self._pf, wb, self._node_starts,
+                self._pf, wb,
             )
             r_s = jax.eval_shape(
                 ft.partial(self._progs["rfs_roots"], search_steps=self.search_steps),

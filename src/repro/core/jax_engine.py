@@ -128,34 +128,30 @@ class FlatDynamicForest(NamedTuple):
 
 
 class PackedForest(NamedTuple):
-    """Position-major merge-tree tables — the packed-plan layout (DESIGN §7).
+    """Position-major merge-tree leaves — the packed-plan layout (DESIGN §7).
 
     The transpose of :class:`FlatForest`: level ℓ buckets 2^ℓ consecutive
-    POSITION-ranks of an edge; inside a bucket events are TIME-sorted and
-    carry inclusive prefix sums of the raw moment block Φ. The swap moves
-    the per-query binary searches from the per-atom axis to the per-node
-    axis: the time boundaries of a window batch are resolved once per
-    (boundary, window, node) in :func:`packed_node_tables` — O(nodes) work,
-    already contracted with q_t — and an atom only converts its three
-    position bounds to a rank interval at the root (:func:`packed_root_ranks`,
-    window-independent, cached in the plan) and walks the canonical
-    ≤2-nodes-per-level decomposition gathering finished per-node values
-    (:func:`packed_walk`). The walk state is [M] ints (no window axis), and
-    each level costs ONE paired gather — the gather-lean executor.
-
-    ``node_base[e, lev]`` maps (edge, walk level, bucket) to the flat node
-    index of the value tables: id = node_base[e, lev] + bucket. The DRFS
-    engine reuses the same walk by supplying the complete-tree node_base.
+    POSITION-ranks of an edge, and a node's window moment is the sum of raw
+    Φ over its leaves whose time falls in the half-window. Only the leaves
+    are stored: per leaf its time key and raw Φ row, in position order,
+    edges laid out by descending ``n_pad`` (``rfs.build_packed_host_tables``)
+    so that every level's nodes are aligned blocks of a leaf prefix.
+    :func:`packed_node_tables` masks the leaves once per window batch and
+    sums pairwise up the tree — no search, no gather — already contracted
+    with q_t; an atom only converts its three position bounds to a rank
+    interval at the root (:func:`packed_root_ranks`, window-independent,
+    cached in the plan) and walks the canonical ≤2-nodes-per-level
+    decomposition gathering finished per-node values (:func:`packed_walk`).
+    The walk state is [M] ints (no window axis), and each level costs ONE
+    paired gather — the gather-lean executor. The walk reads node ids only
+    through the engine's ``node_base_lvl`` [Lmax, E]: id = base + bucket.
     """
 
     pm_pos: jnp.ndarray  # [P] per-edge position-sorted values (+inf pad)
-    pos_base: jnp.ndarray  # [E] flat offset of each edge's pm_pos block
-    pm_time: jnp.ndarray  # [2, T] level-major bucket time keys, time-sorted
-    pm_cum: jnp.ndarray  # [4K, T] inclusive prefix moments (bucket-local, feature-major)
-    edge_base: jnp.ndarray  # [E] flat offset of each edge's level block
+    pos_base: jnp.ndarray  # [E] flat offset of each edge's leaf block
+    pm_time: jnp.ndarray  # [2, P] leaf time keys, same order (+inf pad)
+    pm_phi: jnp.ndarray  # [4K, P] leaf raw Φ rows, same order (feature-major)
     n_pad: jnp.ndarray  # [E] padded event count (power of two; 0 = empty)
-    n_lev: jnp.ndarray  # [E] level count
-    node_base: jnp.ndarray  # [E, Lmax] i32 flat node-id base per walk level
 
 
 class WindowBatch(NamedTuple):
@@ -199,9 +195,9 @@ class TableCodec:
 
       * **fold tables** (q_t-folded node values: :func:`packed_node_tables`,
         :func:`dyn_node_tables`) are stored in ``fold_dtype``. The fold
-        itself always accumulates in f64 (searches, prefix differences and
-        the q_t contraction run on the f64 host tables); only the finished
-        values are cast.
+        itself always accumulates in f64 (the q_t contraction and the node
+        sums or prefix differences run on the f64 host tables); only the
+        finished values are cast.
       * **moment prefixes** (quantized DRFS leaf runs,
         :func:`dyn_window_tables`) are *delta-encoded*: the per-leaf window
         values are quantized to ``moment_dtype`` first and the running
@@ -629,10 +625,10 @@ def _fold_node_level(time_tab, cum_tab, s_lo, s_hi, t_b, right_b, qtl, qtr,
                      steps: int, k_t: int, out_dtype=None):
     """One level's q_t-folded paired node values: [W·2k_s, 2, NL].
 
-    The shared fold of :func:`packed_node_tables` and
-    :func:`dyn_node_tables`: per (boundary, window, node) binary search in
-    the node's time-sorted run [s_lo, s_hi), raw-Φ prefix difference
-    (node-local rounding), combo slice per side/half, q_t contraction.
+    The fold of :func:`dyn_node_tables`: per (boundary, window, node) binary
+    search in the node's time-sorted run [s_lo, s_hi), raw-Φ prefix
+    difference (node-local rounding), combo slice per side/half, q_t
+    contraction.
     Feature-major throughout (``cum_tab`` is [4K, T]): the node axis stays
     the minor axis of every intermediate, which a TPU stores unpadded —
     a trailing axis of 2k_s = 4 values would be padded to 128 lanes.
@@ -673,37 +669,78 @@ def _fold_node_level(time_tab, cum_tab, s_lo, s_hi, t_b, right_b, qtl, qtr,
 def packed_node_tables(
     pf: PackedForest,
     wb: WindowBatch,
-    node_starts,
     *,
-    steps_per_level: tuple,
+    level_nodes: tuple,
     k_t: int,
     out_dtype=None,
 ):
     """q_t-folded paired window values of EVERY position-rank node: [W·C, 2R].
 
-    ``node_starts`` is a tuple of per-level i32 arrays: the flat pm_time
-    offsets of every level-ℓ node's time-sorted run (length 2^ℓ). Per node
-    the three window boundaries are binary-searched in the run — O(nodes)
-    total, NOT O(atoms) — the raw-Φ prefix rows are differenced node-locally
-    and contracted with the temporal query vectors immediately, so the walk
-    gathers finished values. Column side·R + node holds, for every window
-    w, the C = 2k_s values [k_s left-half | k_s right] at rows w·C + j: one
-    walk gather moves every window's value for a node at once. Node ids
-    follow ``pf.node_base`` level-major.
+    Dense: the leaves' time keys are compared once with every window's
+    (lo, mid, hi) boundaries — left half t_lo ≤ t ≤ t_mid, right half
+    t_mid < t ≤ t_hi — and each leaf's Φ, contracted with the half's q_t,
+    is kept where it falls inside. Level ℓ is then the pairwise sum of the
+    first 2·``level_nodes[ℓ]`` columns of level ℓ−1 (the descending-n_pad
+    layout of ``rfs.build_packed_host_tables``), so the build reads the
+    leaves once for all W windows, with no search and no gather. Column
+    side·R + node holds, for every window w, the C = 2k_s values [k_s
+    left-half | k_s right] at rows w·C + j: one walk gather moves every
+    window's value for a node at once. Node ids are level-major, level ℓ
+    starting at Σ_{ℓ'<ℓ} level_nodes[ℓ']. Every intermediate keeps the leaf
+    (node) axis minor, which a TPU stores unpadded, and the q_t contraction
+    is an elementwise multiply-add per window (no GEMM across the window
+    axis), so duplicate window centers stay bitwise identical.
     """
-    t_b, right_b = _dyn_boundaries(wb)
-    qtl, qtr = wb.qt[0::2], wb.qt[1::2]
-    parts = []
-    for lev, ns in enumerate(node_starts):
-        s_lo = ns.astype(jnp.int32)
-        parts.append(
-            _fold_node_level(
-                pf.pm_time, pf.pm_cum, s_lo, s_lo + (1 << lev), t_b, right_b,
-                qtl, qtr, int(steps_per_level[lev]), k_t, out_dtype,
-            )
-        )
-    out = jnp.concatenate(parts, axis=2)
-    return out.reshape(out.shape[0], -1)
+    W = wb.qt.shape[0] // 2
+    K = pf.pm_phi.shape[0] // 4
+    k_s = K // k_t
+    # the (lo, mid, hi) keys of every window center (the bounds of
+    # :func:`_dyn_boundaries`), by reshape: no strided slice, which a TPU
+    # lowers to a gather
+    lo = wb.t_lo.reshape(2, W, 2)[:, :, 0, None]  # [2, W, 1]
+    mid = wb.t_hi.reshape(2, W, 2)[:, :, 0, None]
+    hi = wb.t_hi.reshape(2, W, 2)[:, :, 1, None]
+    t = pf.pm_time[:, None, :]  # [2, 1, P]
+    inside = (
+        ~_key_lt(t, lo) & _key_le(t, mid),  # left half: [W, P]
+        _key_lt(mid, t) & _key_le(t, hi),  # right half
+    )
+    # one row per (side, window, half, s), written straight into level 0:
+    # Φ row (2·side + half)·K + s·k_t + j times q_t[j], the k_t sum
+    # unrolled, kept where the leaf is inside the half
+    lev = jnp.stack([
+        jnp.stack([
+            jnp.where(inside[h][w], sum(
+                pf.pm_phi[(2 * c + h) * K + s * k_t + j] * wb.qt[2 * w + h, j]
+                for j in range(k_t)
+            ), 0.0)
+            for w in range(W) for h in range(2) for s in range(k_s)
+        ])
+        for c in range(2)
+    ])  # [side, W·C, leaf]: level 0, level_nodes[0] wide
+    # the rows of a side fill whole (8, 128) tiles on a TPU, where a
+    # [..., 2, node] array would fill a quarter of each
+    parts = [lev]
+    for n in level_nodes[1:]:
+        lev = _pair_sum(lev, n)
+        parts.append(lev)
+    out = jnp.concatenate([p[c] for c in range(2) for p in parts], axis=1)
+    # codec fold cast: mask, contraction and sums all ran in the leaf-table
+    # dtype; only the finished values shrink
+    return out if out_dtype is None else out.astype(out_dtype)
+
+
+def _pair_sum(x, n: int):
+    """x[..., 2j] + x[..., 2j+1] for j < n, over the minor axis: [..., n].
+
+    A stride-2 window sum (``reduce_window``), which keeps the node axis
+    minor; a strided slice of the minor axis lowers to a gather on a TPU,
+    and a reshape to [..., n, 2] to a relayout with a trailing axis of 2."""
+    nd = x.ndim
+    return jax.lax.reduce_window(
+        x[..., : 2 * n], jnp.zeros((), x.dtype), jax.lax.add,
+        (1,) * (nd - 1) + (2,), (1,) * (nd - 1) + (2,),
+    )
 
 
 def packed_walk(nodeval, node_base_lvl, eid, side, r_lo, r_hi, *, max_levels: int):

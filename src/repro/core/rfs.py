@@ -467,82 +467,79 @@ def _device_nbytes(obj) -> int:
 
 
 def build_packed_host_tables(rf: RangeForest):
-    """Position-major merge-tree tables for the packed-plan executor.
+    """Position-major leaf tables for the packed-plan executor.
 
-    The transpose of the ``RangeForest`` build: level ℓ buckets 2^ℓ
-    consecutive POSITION-ranks; inside a bucket events are time-sorted and
-    carry inclusive prefix sums of raw Φ. Returns a dict of host arrays for
-    ``jax_engine.PackedForest`` plus the per-level node-start offsets
-    (``node_starts``) and per-level search trip counts the node-table
-    builder needs. Block sizes (n_pad, n_levels, edge_base) are shared with
-    the time-major layout, so the two are the same size.
+    Edges are laid out in descending ``n_pad`` order (a stable sort keeps
+    ties in edge-id order), so every edge's leaf block starts at a multiple
+    of its own power-of-two ``n_pad``. Level ℓ of an edge buckets 2^ℓ
+    consecutive POSITION-ranks; with this layout the level-ℓ nodes of every
+    edge with ``n_pad ≥ 2^ℓ`` are exactly the aligned 2^ℓ-blocks of the
+    first P_ℓ leaves, ids ``[0, P_ℓ / 2^ℓ)`` within the level's block, and
+    the edges whose tree ended at level ℓ−1 sort last there. So level ℓ is
+    the pairwise sum of the first 2·NL_ℓ columns of level ℓ−1 — the dense
+    build of ``jax_engine.packed_node_tables`` — with static per-level node
+    counts (``level_nodes``) and no index arrays.
+
+    Returns host arrays for ``jax_engine.PackedForest`` (position-sorted
+    values, and the level-0 leaves' times and raw Φ rows in the same order;
+    +inf / 0 pads), the per-edge ``pos_base`` and ``node_base`` [E, Lmax]
+    (id = node_base[e, ℓ] + bucket, zero past an edge's tree), the static
+    ``level_nodes`` (R = their sum), and ``n_leaves`` = Σ n_pad.
     """
-    net, ee, ctx, phi = rf.net, rf.ee, rf.ctx, rf.phi
-    E = net.n_edges
-    counts = np.diff(ee.ptr)
-    K = ctx.K
+    ee, phi = rf.ee, rf.phi
+    E = rf.net.n_edges
+    K = rf.ctx.K
     n_pad = rf.n_pad
     n_lev = rf.n_levels
-    edge_base = rf.edge_base
     Lmax = max(rf.max_levels, 1)
-    pos_base = np.zeros(E + 1, dtype=np.int64)
-    np.cumsum(n_pad, out=pos_base[1:])
-    P = int(pos_base[-1])
-    T = int(edge_base[-1])
+    order = np.argsort(-n_pad, kind="stable")
+    pos_base = np.zeros(E, dtype=np.int64)
+    pos_base[order] = np.cumsum(n_pad[order]) - n_pad[order]
+    P = int(n_pad.sum())
+    # leaves: each edge's events by position (stable), at its block
+    counts = np.diff(ee.ptr)
+    eid = np.repeat(np.arange(E, dtype=np.int64), counts)
+    by_pos = np.lexsort((ee.pos, eid))
+    dst = pos_base[eid] + np.arange(len(eid)) - ee.ptr[eid]
     pm_pos = np.full(max(P, 1), np.inf)
-    pm_time = np.full(max(T, 1), np.inf)
-    pm_cum = np.zeros((max(T, 1), N_COMBOS, K))
-    node_base = np.zeros((E, Lmax), np.int64)
-    starts: list = [[] for _ in range(Lmax)]
-    nid = 0
-    for lev in range(Lmax):
-        for e in range(E):
-            if lev >= n_lev[e]:
-                continue
-            nb_e = int(n_pad[e]) >> lev
-            node_base[e, lev] = nid
-            starts[lev].append(
-                edge_base[e] + lev * n_pad[e] + np.arange(nb_e, dtype=np.int64) * (1 << lev)
-            )
-            nid += nb_e
-    node_starts = tuple(
-        np.concatenate(s).astype(np.int32) if s else np.zeros(1, np.int32)
-        for s in starts
+    pm_time = np.full(max(P, 1), np.inf)
+    pm_phi = np.zeros((max(P, 1), N_COMBOS, K))
+    pm_pos[dst] = ee.pos[by_pos]
+    pm_time[dst] = ee.time[by_pos]
+    pm_phi[dst] = phi[by_pos]
+    level_nodes = tuple(
+        max(P, 1) if lev == 0 else int(n_pad[n_lev > lev].sum()) >> lev
+        for lev in range(Lmax)
     )
-    for e in range(E):
-        n = int(counts[e])
-        if n == 0:
-            continue
-        npad = int(n_pad[e])
-        lo = int(ee.ptr[e])
-        order0 = np.argsort(ee.pos[lo : lo + n], kind="stable")
-        pm_pos[pos_base[e] : pos_base[e] + n] = ee.pos[lo : lo + n][order0]
-        tms = np.full(npad, np.inf)
-        tms[:n] = ee.time[lo : lo + n][order0]
-        ph = np.zeros((npad, N_COMBOS, K))
-        ph[:n] = phi[lo : lo + n][order0]
-        ranks = np.arange(npad, dtype=np.int64)
-        base = int(edge_base[e])
-        for lev in range(int(n_lev[e])):
-            bucket = ranks >> lev
-            order = np.lexsort((tms, bucket))
-            bptr = np.arange(0, npad + 1, 1 << lev)
-            sl = base + lev * npad
-            pm_time[sl : sl + npad] = tms[order]
-            pm_cum[sl : sl + npad] = segmented_cumsum(ph[order], bptr)
+    lev_base = np.concatenate([[0], np.cumsum(level_nodes)[:-1]]).astype(np.int64)
+    levs = np.arange(Lmax)
+    node_base = np.where(
+        levs[None, :] < n_lev[:, None],
+        lev_base[None, :] + (pos_base[:, None] >> levs[None, :]),
+        0,
+    )
     return dict(
         pm_pos=pm_pos,
-        pos_base=pos_base[:-1],
+        pos_base=pos_base,
         pm_time=pm_time,
-        pm_cum=pm_cum,
-        edge_base=edge_base[:-1].copy(),
+        pm_phi=pm_phi,
         n_pad=n_pad,
-        n_lev=n_lev,
         node_base=node_base.astype(np.int32),
-        node_starts=node_starts,
-        n_nodes=nid,
-        steps_per_level=tuple(lev + 1 for lev in range(Lmax)),
+        level_nodes=level_nodes,
+        n_leaves=P,
     )
+
+
+def packed_node_sums(pm_phi: np.ndarray, level_nodes: tuple) -> np.ndarray:
+    """Raw-Φ sum of every packed node, level-major: [R, 4, K] — the moment
+    magnitudes :func:`jax_engine.packed_node_tables` reaches, for the
+    codec's build-time check."""
+    lev = pm_phi[: level_nodes[0]]
+    parts = [lev]
+    for n in level_nodes[1:]:
+        lev = lev[: 2 * n].reshape((n, 2) + lev.shape[1:]).sum(axis=1)
+        parts.append(lev)
+    return np.concatenate(parts)
 
 
 _JIT_FLUSH = None  # persistent across FlatForestEngine instances: the jit
@@ -598,7 +595,7 @@ def _get_packed():
         )
 
         tables_fn = functools.partial(
-            jax.jit, static_argnames=("steps_per_level", "k_t", "out_dtype")
+            jax.jit, static_argnames=("level_nodes", "k_t", "out_dtype")
         )(packed_node_tables)
         roots_fn = functools.partial(jax.jit, static_argnames=("search_steps",))(
             packed_root_ranks
@@ -640,15 +637,18 @@ class _DeviceEngine:
         self._interpret = pallas_interpret
         self._wb_cache = PlanCache(8)
         # op accounting for QueryStats (n_rank_searches / n_moment_gathers /
-        # bytes_moved): time-boundary search problems solved, prefix/node
-        # moment rows gathered, and the bytes those gathers move (gather
-        # count × gathered-row bytes, so compressed codecs show up directly)
-        # — host-side formulas matching what the jits dispatch.
+        # n_table_leaves / bytes_moved): time-boundary search problems
+        # solved, prefix/node moment rows gathered, leaves folded by the
+        # dense packed table build (W per leaf), and the bytes those read
+        # (gather count × gathered-row bytes, so compressed codecs show up
+        # directly; a dense build reads its leaf tables once) — host-side
+        # formulas matching what the jits dispatch.
         # fused_launches counts tree-phase Pallas kernel launches (the fused
         # executor pays exactly ONE per flush; tests pin this).
         self.counters = {
             "rank_searches": 0,
             "moment_gathers": 0,
+            "table_leaves": 0,
             "bytes_moved": 0,
             "fused_launches": 0,
         }
@@ -824,29 +824,26 @@ class FlatForestEngine(_DeviceEngine):
 
         jnp = self._jnp
         host = build_packed_host_tables(self.rf)
-        # build-time codec validation against the f64 host prefix moments:
-        # a codec that can't round-trip this forest degrades to f64 in place
-        self.codec.validate(host["pm_cum"])
+        # build-time codec validation against the f64 per-node moment sums
+        # the dense build reaches: a codec that can't round-trip this forest
+        # degrades to f64 in place (the identity codec needs no check)
+        if not self.codec.is_identity:
+            self.codec.validate(packed_node_sums(host["pm_phi"], host["level_nodes"]))
         with self._precision():
             pf = PackedForest(
                 pm_pos=jnp.asarray(host["pm_pos"]),
                 pos_base=jnp.asarray(host["pos_base"]),
                 pm_time=jnp.asarray(time_key(host["pm_time"])),
-                pm_cum=jnp.asarray(feature_major(host["pm_cum"])),
-                edge_base=jnp.asarray(host["edge_base"]),
+                pm_phi=jnp.asarray(feature_major(host["pm_phi"])),
                 n_pad=jnp.asarray(host["n_pad"]),
-                n_lev=jnp.asarray(host["n_lev"]),
-                node_base=jnp.asarray(host["node_base"]),
             )
-            node_starts = tuple(jnp.asarray(s) for s in host["node_starts"])
             # walk-level -> node base, transposed for dynamic level indexing
             node_base_lvl = jnp.asarray(host["node_base"].T.copy())
         self._packed = dict(
             pf=pf,
-            node_starts=node_starts,
             node_base_lvl=node_base_lvl,
-            steps_per_level=host["steps_per_level"],
-            n_nodes=int(host["n_nodes"]),
+            level_nodes=host["level_nodes"],
+            n_leaves=int(host["n_leaves"]),
         )
         return self._packed
 
@@ -1017,9 +1014,9 @@ class FlatForestEngine(_DeviceEngine):
         """Per-(window batch) derived tables, LRU-cached by the ts tuple.
 
         packed: q_t-folded paired node values (the plan's core hoist — every
-        time search and every per-node prefix gather happens HERE, at node
-        count scale, never per atom). legacy executors: the [3, W, E]
-        time-rank boundary table shared by every flush of the query.
+        time comparison and every node sum happens HERE, once per leaf and
+        node, never per atom). legacy executors: the [3, W, E] time-rank
+        boundary table shared by every flush of the query.
         """
         key = (ts_key, self.executor, self.codec.name)
         hit = self._tab_cache.get(key)
@@ -1032,19 +1029,19 @@ class FlatForestEngine(_DeviceEngine):
                 pk = self._get_packed_forest()
                 tables_fn, _, _ = _get_packed()
                 tabs = tables_fn(
-                    pk["pf"], wb, pk["node_starts"],
-                    steps_per_level=pk["steps_per_level"],
+                    pk["pf"], wb,
+                    level_nodes=pk["level_nodes"],
                     k_t=int(self.rf.ctx.k_t),
                     out_dtype=self.codec.fold_name,
                 )
-                nn = max(pk["n_nodes"], 1)
-                self.counters["rank_searches"] += 3 * W * nn
-                self.counters["moment_gathers"] += 3 * W * nn
-                # fold gathers read paired raw-Φ prefix rows from the
-                # uncompressed level tables (the codec shrinks only the
-                # DERIVED window tables the per-atom walk gathers from)
-                self.counters["bytes_moved"] += (
-                    3 * W * nn * N_COMBOS * K * self.codec.float_itemsize
+                n = pk["n_leaves"]
+                self.counters["table_leaves"] += W * n
+                # the dense build reads every leaf's raw-Φ row and time key
+                # once for all W windows, from the uncompressed leaf tables
+                # (the codec shrinks only the DERIVED window tables the
+                # per-atom walk gathers from)
+                self.counters["bytes_moved"] += n * (
+                    N_COMBOS * K * self.codec.float_itemsize + 2 * 4
                 )
             else:
                 _, ranks_fn = _get_flush()

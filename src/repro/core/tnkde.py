@@ -100,12 +100,15 @@ class QueryStats:
     n_pending_scanned: int = 0
     n_partial_scanned: int = 0
     # device-engine op accounting (the packed-plan hoist invariants,
-    # DESIGN.md §7): time-boundary binary-search problems solved, and
-    # prefix/node moment rows gathered. Searches scale with the NODE count
-    # of the window tables (zero on a warm plan hit), never with atoms;
-    # the packed walk gathers one paired node row per (level, atom).
+    # DESIGN.md §7): time-boundary binary-search problems solved, prefix/node
+    # moment rows gathered, and leaves folded by the dense packed table
+    # build (W per leaf and window batch). Table work scales with the index
+    # (leaves or nodes; zero on a warm plan hit), never with atoms; the
+    # packed build searches nothing, and the packed walk gathers one paired
+    # node row per (level, atom).
     n_rank_searches: int = 0
     n_moment_gathers: int = 0
+    n_table_leaves: int = 0
     # analytic memory-traffic model of the gathers above: gather count ×
     # gathered-row bytes, same units for every engine/executor, so the
     # fused+codec tier's bytes-per-query claim is measured, not asserted.
@@ -896,6 +899,7 @@ class TNKDE:
             eng = self._fe.counters
             for name, stat in (("rank_searches", "n_rank_searches"),
                                ("moment_gathers", "n_moment_gathers"),
+                               ("table_leaves", "n_table_leaves"),
                                ("bytes_moved", "bytes_moved")):
                 if name not in eng:
                     continue

@@ -70,6 +70,31 @@ SCRIPT = textwrap.dedent(
     res["engine_desc"] = m_rfs.engine_desc
     res["shard_loads"] = [int(x) for x in m_rfs._fe.sf.events_per_shard]
 
+    # ---- dense window tables: each shard on the mesh vs its slab alone ----
+    import jax.numpy as jnp
+    from repro.compat import device_precision
+    from repro.core.jax_engine import PackedForest, time_key
+    from repro.core.rfs import _get_packed, feature_major
+    fe, sf = m_rfs._fe, m_rfs._fe.sf
+    wb = fe.window_batch(fe.rf.ctx, TS)
+    mesh_tabs = np.asarray(fe.window_tables(wb, tuple(TS)))
+    tables_fn = _get_packed()[0]
+    slab_errs = []
+    for s in range(DEV):
+        with device_precision():
+            pf = PackedForest(
+                pm_pos=jnp.asarray(sf.pm_pos[s]), pos_base=jnp.asarray(sf.pos_base[s]),
+                pm_time=jnp.asarray(time_key(sf.pm_time[s])),
+                pm_phi=jnp.asarray(feature_major(sf.pm_phi[s])),
+                n_pad=jnp.asarray(sf.n_pad[s]),
+            )
+            wb1 = jax.tree.map(lambda x: jnp.asarray(np.asarray(x)), wb)
+            one = np.asarray(tables_fn(pf, wb1, level_nodes=sf.level_nodes,
+                                       k_t=int(fe.rf.ctx.k_t)))
+        slab_errs.append(float(np.abs(mesh_tabs[s] - one).max()
+                               / max(np.abs(one).max(), 1e-300)))
+    res["slab_errs"] = slab_errs
+
     # ---- zero steady-state recompiles -------------------------------------
     c0 = jit_entry_count()
     m_rfs.query(TS)
@@ -166,6 +191,10 @@ def _check_matrix(res, devices: int):
         assert err <= 1e-12, (key, err)
     for err in res["stream_errs"]:
         assert err <= 1e-11, res["stream_errs"]
+    # one dense table build per shard, the same on the mesh as alone
+    assert len(res["slab_errs"]) == devices
+    for err in res["slab_errs"]:
+        assert err <= 1e-12, res["slab_errs"]
     # greedy balancing: no shard holds more than 2x the mean event load
     loads = np.array(res["shard_loads"], float)
     assert loads.max() <= 2.0 * max(loads.mean(), 1.0), loads

@@ -1,8 +1,9 @@
 """Packed query plan: hoist invariants, op counters, caches, accounting.
 
-The invariants ISSUE 4 pins (DESIGN.md §7):
-  * time-boundary searches scale with the NODE count of the window tables —
-    never with atoms × windows — and a warm (plan-hit) query pays ZERO;
+The invariants the packed plan pins (DESIGN.md §7):
+  * the window-table build scales with the index — the dense packed build
+    folds W × every leaf and searches nothing — never with atoms × windows,
+    and a warm (plan-hit) query pays ZERO;
   * the packed walk gathers one paired node row per (level, atom): strictly
     fewer moment rows than the legacy cascade executor moves;
   * plans are cached per (epoch, LS) and window tables per ts tuple, so
@@ -28,23 +29,25 @@ def world():
 
 
 def _query_deltas(m, ts):
-    s0 = (m.stats.n_rank_searches, m.stats.n_moment_gathers)
+    s0 = (m.stats.n_rank_searches, m.stats.n_moment_gathers, m.stats.n_table_leaves)
     m.query(ts)
-    return (m.stats.n_rank_searches - s0[0], m.stats.n_moment_gathers - s0[1])
+    return (m.stats.n_rank_searches - s0[0], m.stats.n_moment_gathers - s0[1],
+            m.stats.n_table_leaves - s0[2])
 
 
 def test_rank_searches_scale_with_nodes_not_atoms(world):
-    """Same index, 4x the lixel density -> identical search count."""
+    """Same index, 4x the lixel density -> identical table-build work: the
+    dense packed build folds W x every padded leaf and searches nothing."""
     net, ev = world
     coarse = TNKDE(net, ev, g=80.0, solution="rfs", engine="jax", **KW)
     fine = TNKDE(net, ev, g=20.0, solution="rfs", engine="jax", **KW)
-    s_coarse = _query_deltas(coarse, TS)[0]
-    s_fine = _query_deltas(fine, TS)[0]
+    d_coarse = _query_deltas(coarse, TS)
+    d_fine = _query_deltas(fine, TS)
     assert fine.stats.n_atoms > 2 * coarse.stats.n_atoms  # the load differs
-    assert s_fine == s_coarse > 0  # ... the time-search work does not
-    # and the count is exactly 3 boundaries x W x node count
-    nn = fine._fe._get_packed_forest()["n_nodes"]
-    assert s_fine == 3 * len(TS) * nn
+    assert d_fine[2] == d_coarse[2] > 0  # ... the table-build work does not
+    # and the count is exactly W x the leaves, sum of n_pad
+    assert d_fine[2] == len(TS) * int(fine._fe.rf.n_pad.sum())
+    assert d_fine[0] == d_coarse[0] == 0  # the packed build has no search
 
 
 def test_warm_query_pays_zero_searches(world):
@@ -52,7 +55,8 @@ def test_warm_query_pays_zero_searches(world):
     m = TNKDE(net, ev, g=40.0, solution="rfs", engine="jax", **KW)
     cold = _query_deltas(m, TS)
     warm = _query_deltas(m, TS)
-    assert cold[0] > 0 and warm[0] == 0  # plan hit: no searches at all
+    assert cold[2] > 0 and warm[2] == 0  # plan hit: no leaves folded at all
+    assert cold[0] == warm[0] == 0  # and no searches, cold or warm
     assert warm[1] > 0  # the walk still gathers node rows
     # one paired gather per (level, atom): 2 rows x levels x atoms, summed
     # over level classes -> bounded by 2 * max_levels * atoms per query

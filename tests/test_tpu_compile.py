@@ -36,6 +36,9 @@ N_EDGES = 4378
 N_LIXELS = 28_699
 M_ATOMS = 458_752  # atom block size class of a 400k-atom flush
 LMAX = 12  # walk levels of the npad = 2048 class
+# per-level node counts of the descending-n_pad packed layout (sum N_NODES)
+LEVEL_NODES = (1087136, 543568, 271784, 135892, 67939, 33567, 15862, 7805,
+               3791, 1781, 691, 78)
 COMPILE_LIMIT_S = 240.0  # one compile; an f64 program can stall the compiler
 
 
@@ -189,6 +192,35 @@ def test_packed_flush_compiles(one_chip):
     mem = exe.memory_analysis()
     if mem is not None:  # a small share of one v5e chip's 16 GB
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 4e9, mem
+
+
+def test_packed_tables_compiles(one_chip):
+    """The dense window-table build at the largest window class, f32 as on
+    the chip: no gather in the program, and a small share of the chip."""
+    from repro.core.jax_engine import PackedForest, WindowBatch
+    from repro.core.rfs import _get_packed
+
+    assert sum(LEVEL_NODES) == N_NODES
+    s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
+    tables, _, _ = _get_packed()
+    P = LEVEL_NODES[0]
+    pf = PackedForest(
+        pm_pos=s((P,), jnp.float32), pos_base=s((N_EDGES,), jnp.int32),
+        pm_time=s((2, P), jnp.int32), pm_phi=s((4 * K, P), jnp.float32),
+        n_pad=s((N_EDGES,), jnp.int32),
+    )
+    wb = WindowBatch(
+        t_lo=s((2, 2 * W), jnp.int32), t_hi=s((2, 2 * W), jnp.int32),
+        lo_right=s((2 * W,), jnp.bool_), half=s((2 * W,), jnp.int32),
+        qt=s((2 * W, K_T), jnp.float32),
+    )
+    exe = _compile(lambda: tables.lower(
+        pf, wb, level_nodes=LEVEL_NODES, k_t=K_T, out_dtype=None,
+    ))
+    assert " gather(" not in exe.as_text()
+    mem = exe.memory_analysis()
+    if mem is not None:
+        assert mem.output_size_in_bytes + mem.temp_size_in_bytes < 4e9, mem
 
 
 def test_dyn_flush_compiles(one_chip):
