@@ -42,6 +42,7 @@ from typing import Sequence, Tuple
 
 import numpy as np
 
+from ..spans import span
 from .aggregation import N_COMBOS
 from .query_plan import PlanCache, route_atoms_by_shard
 from .rfs import (
@@ -368,6 +369,27 @@ class _ShardedBase(_DeviceEngine):
 
         self._slab_sharding = NamedSharding(mesh, P(self.axes))
         self._replicated = NamedSharding(mesh, P())
+        # shard_edge_slots / shard_edges_max describe the slab layout (set
+        # once, by _count_slots); psum_bytes adds the heat-delta bytes every
+        # collective flush reduces
+        self.counters.update(shard_edge_slots=0, shard_edges_max=0, psum_bytes=0)
+
+    def _count_slots(self, shard_of: np.ndarray, El: int):
+        """Padded local edge slots over all shards, and the largest shard's
+        own edges: slabs are padded to it, so the table build of every
+        shard runs over ``El`` edge slots."""
+        self.counters["shard_edge_slots"] = int(El) * self.n_shards
+        self.counters["shard_edges_max"] = int(
+            np.bincount(shard_of, minlength=self.n_shards).max(initial=0)
+        )
+
+    def _psum_flush(self, prog, *args, **statics):
+        """Run one collective flush program; ``args[-1]`` is the [L, W]
+        heatmap whose per-shard delta it psums."""
+        heat = args[-1]
+        self.counters["psum_bytes"] += heat.size * heat.dtype.itemsize
+        with self._precision():
+            return prog(*args, **statics)
 
     def _upload(self, x):
         """Replicated upload (window batches, heatmaps): every device of
@@ -419,20 +441,22 @@ class ShardedForestEngine(_ShardedBase):
         self._init_jax()
         self._init_mesh(mesh, axes)
         self.rf = rf
-        self.sf = build_sharded_packed(rf, self.n_shards)
-        self.max_levels = self.sf.max_levels
-        self.search_steps = self.sf.search_steps
         from .jax_engine import PackedForest
 
-        with self._precision():
-            self._nbl = self._shard_put(self.sf.node_base_lvl)
-            self._pf = PackedForest(
-                pm_pos=self._shard_put(self.sf.pm_pos),
-                pos_base=self._shard_put(self.sf.pos_base),
-                pm_time=self._shard_put(_slab_keys(self.sf.pm_time)),
-                pm_phi=self._shard_put(feature_major(self.sf.pm_phi)),
-                n_pad=self._shard_put(self.sf.n_pad),
-            )
+        with span("shard.slab"):
+            self.sf = build_sharded_packed(rf, self.n_shards)
+            with self._precision():
+                self._nbl = self._shard_put(self.sf.node_base_lvl)
+                self._pf = PackedForest(
+                    pm_pos=self._shard_put(self.sf.pm_pos),
+                    pos_base=self._shard_put(self.sf.pos_base),
+                    pm_time=self._shard_put(_slab_keys(self.sf.pm_time)),
+                    pm_phi=self._shard_put(feature_major(self.sf.pm_phi)),
+                    n_pad=self._shard_put(self.sf.n_pad),
+                )
+        self.max_levels = self.sf.max_levels
+        self.search_steps = self.sf.search_steps
+        self._count_slots(self.sf.shard_of_edge, self.sf.pos_base.shape[1])
         self._tab_cache = PlanCache(2)
         self._pack_cache = PlanCache(2)
         self._mesh_key = (tuple(sorted(mesh.shape.items())), self.axes)
@@ -496,11 +520,11 @@ class ShardedForestEngine(_ShardedBase):
             return heat
         tabs = self.window_tables(wb, ts_key)
         for entry in self._atom_packs(plan):
-            with self._precision():
-                heat = self._progs["rfs_flush"](
-                    tabs, self._nbl, entry["fa"], entry["r_lo"], entry["r_hi"],
-                    heat, max_levels=self.max_levels,
-                )
+            heat = self._psum_flush(
+                self._progs["rfs_flush"],
+                tabs, self._nbl, entry["fa"], entry["r_lo"], entry["r_hi"],
+                heat, max_levels=self.max_levels,
+            )
             self.counters["moment_gathers"] += 2 * self.max_levels * entry["m"]
         return heat
 
@@ -583,6 +607,7 @@ class ShardedDynamicEngine(_ShardedBase):
         ]
         for s, o in enumerate(self._owned):
             self._own_mask[s][o] = True
+        self._count_slots(self.shard_of, self.El)
         self._sealed_packs: "OrderedDict" = OrderedDict()
         self._pend_packs: "OrderedDict" = OrderedDict()
         self._tab_cache: "OrderedDict" = OrderedDict()
@@ -611,6 +636,17 @@ class ShardedDynamicEngine(_ShardedBase):
         if pack is not None:
             self._sealed_packs.move_to_end(key)
             return pack
+        with span("shard.slab", revision=snap.revision):
+            pack = self._slab_sealed(snap)
+        self._sealed_packs[key] = pack
+        while len(self._sealed_packs) > self.max_snapshots:
+            old_key, _ = self._sealed_packs.popitem(last=False)
+            for tk in [k for k in self._tab_cache if k[1:3] == old_key]:
+                del self._tab_cache[tk]
+        return pack
+
+    def _slab_sealed(self, snap) -> _ShardedSealed:
+        """Slab the snapshot's sealed level CSRs on the host and upload."""
         S, El = self.n_shards, self.El
         E = snap.net.n_edges
         Lv = snap.depth + 1
@@ -654,11 +690,6 @@ class ShardedDynamicEngine(_ShardedBase):
         # the two differ under index packing / canonicalization, and
         # _device_nbytes is the one accounting helper everywhere else
         pack.nbytes = _device_nbytes(pack.tables)
-        self._sealed_packs[key] = pack
-        while len(self._sealed_packs) > self.max_snapshots:
-            old_key, _ = self._sealed_packs.popitem(last=False)
-            for tk in [k for k in self._tab_cache if k[1:3] == old_key]:
-                del self._tab_cache[tk]
         return pack
 
     def _get_pending(self, snap) -> _ShardedPend:
@@ -668,6 +699,15 @@ class ShardedDynamicEngine(_ShardedBase):
         if pack is not None:
             self._pend_packs.move_to_end(key)
             return pack
+        with span("shard.slab", pend_revision=key):
+            pack = self._slab_pending(snap)
+        self._pend_packs[key] = pack
+        while len(self._pend_packs) > self.max_snapshots + 2:
+            self._pend_packs.popitem(last=False)
+        return pack
+
+    def _slab_pending(self, snap) -> _ShardedPend:
+        """Slab the snapshot's pending CSR on the host and upload."""
         S, El = self.n_shards, self.El
         E = snap.net.n_edges
         K = snap.ctx.K
@@ -708,9 +748,6 @@ class ShardedDynamicEngine(_ShardedBase):
                 pend_phi=self._shard_put(feature_major(pf)),
             )
         pack.nbytes = _device_nbytes(pack.tables)
-        self._pend_packs[key] = pack
-        while len(self._pend_packs) > self.max_snapshots + 2:
-            self._pend_packs.popitem(last=False)
         return pack
 
     def _forest(self, sealed: _ShardedSealed, pend: _ShardedPend):
@@ -816,11 +853,11 @@ class ShardedDynamicEngine(_ShardedBase):
                 2 * (hq + 1) * entry["m"] if exact_leaf else 2 * entry["m"]
             )
             leaves = self._leaf_pack(entry, snap, hq)
-            with self._precision():
-                heat = self._progs["dyn_flush"](
-                    forest, entry["fa"], wb, tables, leaves, heat,
-                    n_levels=sealed.n_levels, hq=int(hq),
-                    scan_steps=int(scan_steps), pend_steps=int(pend.pend_steps),
-                    exact=bool(exact_leaf),
-                )
+            heat = self._psum_flush(
+                self._progs["dyn_flush"],
+                forest, entry["fa"], wb, tables, leaves, heat,
+                n_levels=sealed.n_levels, hq=int(hq),
+                scan_steps=int(scan_steps), pend_steps=int(pend.pend_steps),
+                exact=bool(exact_leaf),
+            )
         return heat

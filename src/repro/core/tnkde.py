@@ -50,7 +50,11 @@ the effect in the same units for every executor.
 with a psum of the heatmap, so sharded == single-host to summation-order
 noise, index memory per device scales ~1/shards (``QueryStats.
 bytes_per_shard``), and streaming DRFS mutation (insert/seal/extend)
-works unchanged. rfs/drfs only; the packed executor only.
+works unchanged. rfs/drfs only; the packed executor only. ``shards`` states
+the same as a number, for a deployment that cannot carry a ``Mesh``:
+``shards > 1`` without a mesh builds ``compat.host_mesh(shards)`` over the
+first ``shards`` local devices; with a mesh it must be 1 or the mesh's
+extent over ``shard_axes``.
 """
 from __future__ import annotations
 
@@ -119,6 +123,36 @@ class QueryStats:
     # (one shard); the sharded engines report one slab — the MEASURED form
     # of the 1/devices memory-scaling claim (DESIGN.md §3).
     bytes_per_shard: int = 0
+    # sharded engines only (DESIGN.md §3): padded local edge slots over all
+    # shards and the largest shard's own edges (the slab layout; every
+    # shard's table build runs over the largest's slots), and the heat-delta
+    # bytes the collective flushes psum'd
+    shard_edge_slots: int = 0
+    shard_edges_max: int = 0
+    psum_bytes: int = 0
+
+
+def _resolve_mesh(mesh, shard_axes: Sequence[str], shards: int):
+    """The mesh a model shards over: ``mesh`` as given (``shards`` must then
+    be 1 or its extent over ``shard_axes``), else one over the first
+    ``shards`` local devices when ``shards > 1``, else None. Never falls
+    back to fewer devices than stated."""
+    shards = int(shards)
+    if shards < 1:
+        raise ValueError(f"shards must be at least 1, got {shards}")
+    if mesh is not None:
+        extent = int(np.prod([mesh.shape.get(a, 1) for a in shard_axes]))
+        if shards not in (1, extent):
+            raise ValueError(
+                f"shards={shards} disagrees with the mesh's {extent} shards "
+                f"over {tuple(shard_axes)}"
+            )
+        return mesh
+    if shards == 1:
+        return None
+    from ..compat import host_mesh
+
+    return host_mesh(shards, shard_axes)
 
 
 class TNKDE:
@@ -138,6 +172,7 @@ class TNKDE:
         table_codec: str = "auto",
         mesh=None,
         shard_axes: Sequence[str] = ("data",),
+        shards: int = 1,
         lixel_sharing: bool = False,
         cascade: bool = True,
         drfs_depth: int = 8,
@@ -163,6 +198,7 @@ class TNKDE:
             raise ValueError("search/cascade executors are rfs-only")
         if table_codec not in ("auto", "f64", "f32", "bf16"):
             raise ValueError(f"unknown table_codec {table_codec!r}")
+        mesh = _resolve_mesh(mesh, tuple(shard_axes), shards)
         if mesh is not None:
             if solution not in ("rfs", "drfs"):
                 raise ValueError("mesh= shards the forest indexes (rfs/drfs)")
@@ -900,7 +936,8 @@ class TNKDE:
             for name, stat in (("rank_searches", "n_rank_searches"),
                                ("moment_gathers", "n_moment_gathers"),
                                ("table_leaves", "n_table_leaves"),
-                               ("bytes_moved", "bytes_moved")):
+                               ("bytes_moved", "bytes_moved"),
+                               ("psum_bytes", "psum_bytes")):
                 if name not in eng:
                     continue
                 cur = int(eng[name])
@@ -910,6 +947,9 @@ class TNKDE:
                 setattr(self.stats, stat, getattr(self.stats, stat) + cur - prev)
                 self._counter_cursor[name] = cur
             self.stats.bytes_per_shard = self._fe.bytes_per_shard
+            for name in ("shard_edge_slots", "shard_edges_max"):
+                if name in eng:
+                    setattr(self.stats, name, int(eng[name]))
 
     def query(self, ts: Sequence[float], *, at=None) -> np.ndarray:
         """KDE values for every lixel, for each window center in ts: [W, L].

@@ -80,6 +80,10 @@ class ProfileConfig:
     # once at server level; profiles may have heterogeneous horizons).
     auto_seal: bool = True
     horizon_s: Optional[float] = None
+    # devices the profile's index is sharded over (TNKDE(shards=)): a
+    # deployment states its shard count as data; the server's mesh=, where
+    # given, must agree with it
+    shards: int = 1
 
     def to_kwargs(self) -> dict:
         return dataclasses.asdict(self)
@@ -191,7 +195,9 @@ class TNKDEServer:
         ``shard_axes`` (DESIGN.md §3): batched, epoch-pinned queries
         then answer from the sharded packed engines — the MVCC pins work
         unchanged because the sharded DRFS engine packs per snapshot epoch
-        exactly like the single-host one."""
+        exactly like the single-host one. A profile's ``shards`` must then
+        be 1 or the mesh's extent; without ``mesh``, a profile stating
+        ``shards > 1`` shards over the first that many devices."""
         profiles = profiles or {"default": ProfileConfig()}
         self.profiles = {
             name: (p if isinstance(p, ProfileConfig) else ProfileConfig(**p))

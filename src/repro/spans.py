@@ -13,6 +13,9 @@ import sys
 
 SPANS = ("serve.dispatch", "tnkde.plan", "tnkde.window_batch", "tnkde.enqueue",
          "serve.retire", "tnkde.transfer", "tnkde.ls_sweep", "serve.assemble")
+# the sharded engines' (DESIGN.md §3): the host slab build and upload of an
+# index epoch, at construction and at the first query of a new epoch
+SHARD_SPANS = ("shard.slab",)
 
 
 def span(name: str, **ids):

@@ -263,3 +263,72 @@ def test_dyn_flush_compiles(one_chip):
     mem = exe.memory_analysis()
     if mem is not None:
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 6e9, mem
+
+
+@pytest.fixture(scope="module")
+def four_chips(one_chip):
+    """The described v5e 2x2 as a one-axis mesh, as ``TNKDE(shards=4)``
+    builds it over the first four devices."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    return Mesh(np.array(topo.devices).reshape(4), ("data",))
+
+
+def test_sharded_drfs_programs_compile_for_a_2x2(four_chips):
+    """The four-chip cell's programs at San Francisco's per-shard shapes
+    (4,002 edges and 1,210,280 sealed events a shard, 64,776 lixels): the
+    window tables and the flush, whose psum is its one all-reduce, each
+    within its share of a chip beside the cached table sets."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core.distributed import _get_programs
+    from repro.core.jax_engine import FlatAtoms, FlatDynamicForest, WindowBatch
+
+    slab, rep = NamedSharding(four_chips, P("data")), NamedSharding(four_chips, P())
+
+    def s(shape, dt, sh=slab):  # slab arrays lead with the shard axis
+        return _spec(sh, (4,) + shape if sh is slab else shape, dt)
+
+    El, Lv, Np, Pp, L, Mp, Wd = 4002, HQ + 1, 1_310_720, 1_310_720, 64_776, 57_344, 16
+    forest = FlatDynamicForest(
+        time_lvl=s((2, Lv * Np), jnp.int32), pos_lvl=s((Lv * Np,), jnp.float32),
+        cum_lvl=s((4 * K, Lv * Np), jnp.float32),
+        node_ptr=s((El * ((1 << Lv) - 1) + Lv,), jnp.int32),
+        pend_ptr=s((El + 1,), jnp.int32), pend_pos=s((Pp,), jnp.float32),
+        pend_time=s((2, Pp), jnp.int32), pend_phi=s((4 * K, Pp), jnp.float32),
+    )
+    wb = WindowBatch(
+        t_lo=s((2, 2 * Wd), jnp.int32, rep), t_hi=s((2, 2 * Wd), jnp.int32, rep),
+        lo_right=s((2 * Wd,), jnp.bool_, rep), half=s((2 * Wd,), jnp.int32, rep),
+        qt=s((2 * Wd, K_T), jnp.float32, rep),
+    )
+    fa = FlatAtoms(
+        lixel=s((Mp,), jnp.int32), edge=s((Mp,), jnp.int32),
+        side_feat=s((Mp,), jnp.int32), qs=s((Mp, K_S), jnp.float32),
+        pos_hi=s((Mp,), jnp.float32), pos_lo1=s((Mp,), jnp.float32),
+        lo1_right=s((Mp,), jnp.bool_), pos_lo2=s((Mp,), jnp.float32),
+        valid=s((Mp,), jnp.bool_),
+    )
+    progs = _get_programs(four_chips, ("data",))
+    with jax.enable_x64(False), jax.default_matmul_precision("highest"):
+        tables = _compile(lambda: progs["dyn_tables"].lower(
+            forest, wb, n_levels=Lv, hq=HQ, search_steps=8, steps_per_level=(),
+            exact=False,
+        ))
+        lcum = s((Wd * 2 * K, 2 * El * ((1 << HQ) + 1)), jnp.float32)
+        flush = _compile(lambda: progs["dyn_flush"].lower(
+            forest, fa, wb, (lcum,), s((Mp, 4), jnp.int32),
+            s((L, Wd), jnp.float32, rep), n_levels=Lv, hq=HQ, scan_steps=0,
+            pend_steps=0, exact=False,
+        ))
+    assert "all-reduce" not in tables.as_text()
+    assert "all-reduce" in flush.as_text()
+    mem = tables.memory_analysis()
+    if mem is not None:  # 7 cached W = 16 table sets (7.4 GB) sit beside it
+        assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes) < 8e9, mem
+    mem = flush.memory_analysis()
+    if mem is not None:
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 6e9, mem
